@@ -20,11 +20,11 @@ table.  Prints ``name,value,derived`` CSV blocks.
                  vs simulated grid (bit-identical results, wall-clock
                  time-to-first-partial; BENCH_backend.json)
   query_spmd   - SPMD grid-brick query step micro-benchmark (real compute)
-  perf_probe   - lower one (arch x shape) cell and report roofline terms
-                 (subprocess: the probe must set XLA_FLAGS before jax
-                 imports; skipped gracefully on timeout/failure)
-  roofline     - per-(arch x shape) terms from the dry-run artifacts
-                 (skipped unless artifacts exist; see launch/dryrun.py)
+
+Everything runs in this one process, and a failing phase fails the run.
+The LM dry-run tools (``benchmarks/perf_probe.py``,
+``benchmarks/bench_roofline.py``) lower LM cells on fake CPU devices and
+run on their own.
 
 ``--smoke`` runs every bench in a tiny configuration with perf asserts
 and snapshot writes disabled — the CI job that keeps benchmarks from
@@ -49,6 +49,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.smoke:
         os.environ["BENCH_SMOKE"] = "1"
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"compile cache: {enable_compile_cache()}")
 
     _section("crossover (paper Fig 7)")
     from benchmarks import bench_crossover
@@ -86,19 +88,24 @@ def main(argv=None) -> None:
     from benchmarks import bench_backend
     bench_backend.main()
 
-    _section("spmd query step (grid-brick job, wall time on this host)")
     import jax
     import jax.numpy as jnp
     from repro.configs.geps_events import reduced
     from repro.core import events as ev
     from repro.core.brick import create_store, gather_store
     from repro.core.jse import spmd_query_step
+    from repro.kernels import resolve_interpret
+
+    _section(f"spmd query step (grid-brick job, wall time on "
+             f"{jax.devices()[0].device_kind})")
 
     schema = ev.EventSchema.from_config(reduced())
     store = create_store(schema, n_events=1024 if args.smoke else 4096,
                          n_nodes=4, events_per_brick=256, replication=2,
                          seed=5)
     batch = {k: jnp.asarray(v) for k, v in gather_store(store).items()}
+    pallas_label = ("pallas_interpret" if resolve_interpret(None)
+                    else "pallas")
     for use_pallas in (False, True):
         step = jax.jit(spmd_query_step(
             "e_total > 40 && count(pt > 15) >= 2", schema, calib_iters=4,
@@ -110,41 +117,9 @@ def main(argv=None) -> None:
             out = step(batch)
             jax.block_until_ready(out)
         us = (time.perf_counter() - t0) / 5 * 1e6
-        label = "pallas_interpret" if use_pallas else "xla"
+        label = pallas_label if use_pallas else "xla"
         print(f"query_spmd_{label},{us:.0f}us_per_call,"
               f"selected={int(out['n_selected'])}")
-
-    _section("perf probe (lower one cell, roofline terms)")
-    # subprocess on purpose: the probe must set XLA_FLAGS (host device
-    # count) BEFORE jax is imported, and this harness imported jax above
-    import pathlib
-    import subprocess
-    import sys
-    probe_arch = "xlstm-350m" if args.smoke else "starcoder2-3b"
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.perf_probe",
-             "--arch", probe_arch, "--shape", "train_4k"],
-            capture_output=True, text=True,
-            timeout=240 if args.smoke else 600,
-            cwd=pathlib.Path(__file__).resolve().parent.parent,
-            env={**os.environ,
-                 "PYTHONPATH": "src" + os.pathsep
-                 + os.environ.get("PYTHONPATH", "")})
-        if proc.returncode == 0:
-            print(proc.stdout.strip())
-        else:
-            print(f"perf_probe,skipped,rc={proc.returncode}: "
-                  f"{proc.stderr.strip().splitlines()[-1][:120] if proc.stderr.strip() else ''}")
-    except subprocess.TimeoutExpired:
-        print("perf_probe,skipped,timeout")
-
-    _section("roofline (from dry-run artifacts)")
-    try:
-        from benchmarks import bench_roofline
-        bench_roofline.main(["--mesh", "16x16"])
-    except Exception as e:  # noqa: BLE001
-        print(f"roofline,skipped,{e!r:.120}")
 
     print("\nall benchmarks done")
 

@@ -35,3 +35,17 @@ CONFIG = EventWorkloadConfig()
 def reduced() -> EventWorkloadConfig:
     return EventWorkloadConfig(
         n_scalars=8, max_tracks=32, track_vars=7, events_per_brick=16)
+
+
+#: Event widths by name: the paper's ~1 MB event and the reduced width the
+#: tests and CPU runs use.
+WIDTHS = ("paper", "reduced")
+
+
+def by_width(name: str) -> EventWorkloadConfig:
+    """The workload config of a named width (see :data:`WIDTHS`)."""
+    if name == "paper":
+        return CONFIG
+    if name == "reduced":
+        return reduced()
+    raise ValueError(f"unknown event width {name!r} (choose from {WIDTHS})")

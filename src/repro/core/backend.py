@@ -45,7 +45,6 @@ mesh sharding, interpret auto-detect, double buffering).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Callable, Dict, List, Optional, Protocol, Tuple, \
     runtime_checkable
@@ -304,44 +303,6 @@ class _Inflight:
     ids: Optional[np.ndarray] = None
 
 
-@functools.lru_cache(maxsize=64)
-def _sharded_kernel_call(n_dev: int, var_idx: Tuple[int, ...],
-                         calib_iters: int, interpret: Optional[bool],
-                         block_e: int, block_t: int):
-    """Build (and cache) the jitted ``shard_map`` kernel call for a
-    ``(1, "scan")`` device mesh: the stacked ``(D, n, ...)`` chunk slabs
-    are sharded over the leading axis (each device owns one sub-chunk —
-    the logical sharding constraint), thresholds replicated, outputs
-    sharded back.  Reuses the exact version-compat idiom proven in
-    ``core/brick_attention.py``."""
-    import jax
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    try:  # jax >= 0.5 exposes shard_map at top level
-        _shard_map = jax.shard_map
-        _sm_nocheck = {"check_vma": False}
-    except AttributeError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _shard_map
-        _sm_nocheck = {"check_rep": False}
-
-    from repro.kernels.event_filter.kernel import event_filter_batch_pallas
-
-    mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("scan",))
-
-    def body(sc, tr, ntr, thr):
-        # per-device view: leading axis is this shard's single sub-chunk
-        mask, var = event_filter_batch_pallas(
-            sc[0], tr[0], ntr[0], thr, var_idx=var_idx,
-            calib_iters=calib_iters, interpret=interpret,
-            block_e=block_e, block_t=block_t)
-        return mask[None], var[None]
-
-    fn = _shard_map(body, mesh=mesh,
-                    in_specs=(P("scan"), P("scan"), P("scan"), P(None, None)),
-                    out_specs=(P("scan"), P("scan")), **_sm_nocheck)
-    return jax.jit(fn)
-
-
 class SpmdBackend:
     """The SPMD realization of the contract: a chunked streaming scan
     over the brick shards.
@@ -447,6 +408,7 @@ class SpmdBackend:
         self.last_autotune = None
         # resolved lazily on first run (jax import deferred until needed)
         self._mesh_real: Optional[bool] = None
+        self._mesh = None
 
     # ------------------------------------------------------------------ #
     def _chunk_size(self, seq: int, remaining: int, ramp: Optional[int],
@@ -499,15 +461,34 @@ class SpmdBackend:
     def _mesh_is_real(self) -> bool:
         """True when jax actually has ``mesh_devices`` devices (the
         ``shard_map`` fast path); False emulates the mesh with lockstep
-        critical-path accounting.  Resolved once — jax pins its device
+        critical-path accounting, which only the CPU backend may do: on
+        an accelerator a mesh wider than the devices raises instead of
+        reporting made-up times.  Resolved once — jax pins its device
         count at first init."""
         if self._mesh_real is None:
             if self.mesh_devices <= 1:
                 self._mesh_real = False
             else:
                 import jax
-                self._mesh_real = len(jax.devices()) >= self.mesh_devices
+                n_dev = len(jax.devices())
+                if n_dev < self.mesh_devices \
+                        and jax.default_backend() != "cpu":
+                    raise ValueError(
+                        f"mesh_devices={self.mesh_devices} but the "
+                        f"{jax.default_backend()} backend has {n_dev} "
+                        f"device(s)")
+                self._mesh_real = n_dev >= self.mesh_devices
         return self._mesh_real
+
+    def _scan_mesh(self):
+        """The 1-D ``"scan"`` mesh over the first ``mesh_devices``
+        devices (real-mesh path only; built once)."""
+        if self._mesh is None:
+            import jax
+            from jax.sharding import Mesh
+            self._mesh = Mesh(np.asarray(jax.devices()[:self.mesh_devices]),
+                              ("scan",))
+        return self._mesh
 
     def _maybe_autotune(self, split: PlanSplit, brick_id: int,
                         calib_iters: int) -> Tuple[int, int]:
@@ -588,11 +569,16 @@ class SpmdBackend:
         packetization — and therefore prefix bit-identity — is unchanged
         by the group width.  jnp sub-batch targets (mixed windows) run
         per slot on the host path as usual."""
+        import jax
         import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.kernels import resolve_interpret
+        from repro.kernels.event_filter import ops as ef_ops
         batch = self.store.bricks[brick_id]
         n_max = max(size for _, _, size in slots)
         d = self.mesh_devices
+        mesh = self._scan_mesh()
+        per_device = NamedSharding(mesh, P("scan"))
 
         def slab(key, start, size):
             a = np.asarray(batch[key][start:start + size])
@@ -605,12 +591,14 @@ class SpmdBackend:
             rows = [slab(key, start, size) for _, start, size in slots]
             while len(rows) < d:    # tail group: replicate a dummy slab
                 rows.append(np.zeros_like(rows[0]))
-            return jnp.asarray(np.stack(rows))
+            # each slab goes straight to the device that owns it
+            return jax.device_put(np.stack(rows), per_device)
 
         be, bt = block_shapes
-        fn = _sharded_kernel_call(d, split.var_idx, calib_iters,
-                                  resolve_interpret(self.interpret),
-                                  be, bt)
+        fn = ef_ops.sharded_event_filter_batch(
+            mesh, var_idx=split.var_idx, calib_iters=calib_iters,
+            interpret=resolve_interpret(self.interpret), block_e=be,
+            block_t=bt)
         gmask, gvar = fn(stacked("scalars"), stacked("tracks"),
                          stacked("n_tracks"), split.thresholds)
         out: List[_Inflight] = []
@@ -684,8 +672,7 @@ class SpmdBackend:
         bricks = sorted(rec.bricks)
         block_shapes = (self._maybe_autotune(split, bricks[0],
                                              rec.calib_iters)
-                        if bricks and self.use_pallas
-                        else (128, 512))
+                        if bricks and self.use_pallas else None)
         mesh = max(1, self.mesh_devices)
         lockstep = mesh > 1 and not self._mesh_is_real()
         # with enough physical devices AND kernel targets, whole groups

@@ -87,6 +87,17 @@ def create_store(schema: ev.EventSchema, *, n_events: int, n_nodes: int,
     return BrickStore(schema, bricks, specs, n_nodes)
 
 
+def store_from_config(cfg, *, n_events: int, n_nodes: int,
+                      seed: int = 0) -> BrickStore:
+    """The store a ``geps_events`` workload config describes — its event
+    width, brick size and replication — holding ``n_events`` synthetic
+    events generated from ``seed``."""
+    return create_store(ev.EventSchema.from_config(cfg), n_events=n_events,
+                        n_nodes=n_nodes,
+                        events_per_brick=cfg.events_per_brick,
+                        replication=cfg.replication_factor, seed=seed)
+
+
 # --------------------------------------------------------------------------- #
 # SPMD realization
 # --------------------------------------------------------------------------- #

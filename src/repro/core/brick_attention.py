@@ -24,13 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    _shard_map = jax.shard_map
-    _SM_NOCHECK = {"check_vma": False}
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_NOCHECK = {"check_rep": False}
-
 from repro.models.attention import NEG_INF, repeat_kv
 from repro.models.layers import softcap as apply_softcap
 
@@ -69,7 +62,7 @@ def decode_attention(
         scale=scale,
         logit_cap=cfg.attn_logit_softcap,
     )
-    return _shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -87,7 +80,7 @@ def decode_attention(
             P(batch, "model", None, None),
             P(batch, "model", None, None),
         ),
-        **_SM_NOCHECK,
+        check_vma=False,
     )(q, k_cache, v_cache, kpos, new_k, new_v, slot, t)
 
 

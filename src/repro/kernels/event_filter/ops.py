@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import query as q
-from repro.kernels.event_filter.kernel import (event_filter_batch_pallas,
+from repro.kernels.event_filter.kernel import (DEFAULT_BLOCK,
+                                               event_filter_batch_pallas,
                                                event_filter_pallas)
 from repro.kernels.event_filter.ref import (event_filter_batch_ref,
                                             event_filter_ref)
@@ -131,8 +132,9 @@ def match_epilogue(target, schema) -> Optional[dict]:
                                              "block_e", "block_t"))
 def event_filter(scalars, tracks, n_tracks, thresholds, *, var_idx: int,
                  calib_iters: int, interpret: Optional[bool] = None,
-                 use_pallas: bool = True, block_e: int = 128,
-                 block_t: int = 512):
+                 use_pallas: bool = True,
+                 block_e: int = DEFAULT_BLOCK[0],
+                 block_t: int = DEFAULT_BLOCK[1]):
     """Jitted single-query kernel dispatch: the Pallas path
     (``use_pallas=True``) or the jnp reference.  ``interpret=None``
     auto-detects (compiled on TPU/GPU, interpreter on CPU); ``block_e`` /
@@ -179,8 +181,9 @@ def filter_and_summarize(expr: str, schema, batch, *, calib_iters: int = 0,
 def event_filter_batch(scalars, tracks, n_tracks, thresholds, *,
                        var_idx: Tuple[int, ...], calib_iters: int,
                        interpret: Optional[bool] = None,
-                       use_pallas: bool = True, block_e: int = 128,
-                       block_t: int = 512):
+                       use_pallas: bool = True,
+                       block_e: int = DEFAULT_BLOCK[0],
+                       block_t: int = DEFAULT_BLOCK[1]):
     """Jitted K-query kernel dispatch (see :func:`event_filter` for the
     flag semantics; thresholds are the ``(4, K)`` layout from
     :func:`batch_kernel_params`)."""
@@ -192,6 +195,31 @@ def event_filter_batch(scalars, tracks, n_tracks, thresholds, *,
     return event_filter_batch_ref(
         scalars, tracks, n_tracks, thresholds, var_idx=var_idx,
         calib_iters=calib_iters)
+
+
+@functools.lru_cache(maxsize=64)
+def sharded_event_filter_batch(mesh, *, var_idx: Tuple[int, ...],
+                               calib_iters: int, interpret: Optional[bool],
+                               block_e: int = DEFAULT_BLOCK[0],
+                               block_t: int = DEFAULT_BLOCK[1]):
+    """The jitted ``shard_map`` kernel call over a 1-D ``"scan"`` mesh:
+    stacked ``(D, n, ...)`` chunk slabs are sharded over the leading axis
+    (each device owns one sub-chunk and runs the batched kernel on it),
+    thresholds replicated, ``(D, n, K)`` masks and ``(D, n)`` vars
+    sharded back.  Cached per mesh and static kernel parameters."""
+    from jax.sharding import PartitionSpec as P
+
+    def body(sc, tr, ntr, thr):
+        mask, var = event_filter_batch_pallas(
+            sc[0], tr[0], ntr[0], thr, var_idx=var_idx,
+            calib_iters=calib_iters, interpret=interpret,
+            block_e=block_e, block_t=block_t)
+        return mask[None], var[None]
+
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P("scan"), P("scan"), P("scan"), P(None, None)),
+        out_specs=(P("scan"), P("scan")), check_vma=False))
 
 
 def filter_and_summarize_batch(exprs, schema, batch, *, calib_iters: int = 0,

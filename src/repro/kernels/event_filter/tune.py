@@ -1,10 +1,10 @@
 """Block-shape autotuner for the fused ``event_filter`` kernel.
 
-The kernel's ``(block_e, block_t)`` block shapes were a fixed
-``(128, 512)`` — fine for the TPU tiling the BlockSpecs were written
-against, wrong in general: the best shape depends on the chunk shape the
-SPMD scan actually feeds (``chunk_events`` x tracks x vars), the query
-width K, and whether the kernel runs compiled or interpreted.  This
+The kernel's ``(block_e, block_t)`` block shapes default to
+:data:`~repro.kernels.event_filter.kernel.DEFAULT_BLOCK`, but the best
+shape depends on the chunk shape the SPMD scan actually feeds
+(``chunk_events`` x tracks x vars), the query width K, and whether the
+kernel runs compiled or interpreted.  This
 module measures instead of guessing:
 
 - :func:`autotune_block_shapes` sweeps :data:`CANDIDATES` on a sample
@@ -13,7 +13,8 @@ module measures instead of guessing:
   under, and returns a :class:`TunedShape` carrying the winner, the
   fixed-default baseline, every measurement, and a roofline point
   (bytes moved / useful FLOPs / achieved GB/s and GFLOP/s at the
-  winner's runtime).
+  winner's runtime).  Candidates that cannot compile at the chunk's
+  width (VMEM overflow, :func:`candidates_for`) are not swept.
 - Winners are cached **in-process** by :func:`shape_key` (chunk shape x
   schema width x K x calib x interpret), so a scan pays the sweep once
   per shape class; ``BENCH_backend.json`` persists the roofline points
@@ -26,17 +27,22 @@ import time
 from typing import Dict, Optional, Sequence, Tuple
 
 from repro.kernels import resolve_interpret
+from repro.kernels.event_filter.kernel import (DEFAULT_BLOCK, VMEM_BUDGET,
+                                               vmem_bytes)
 
-#: The sweep grid: small-event blocks for small streaming chunks, the
-#: historical (128, 512) default, and wider track tiles for track-heavy
-#: schemas.  Candidates clamp to the operand shape, so an oversized
-#: entry is timed at most once (see the dedup in the sweep).
+#: The sweep grid: few-event blocks with long track tiles for the
+#: paper's 1 MB events, wider event blocks for narrow schemas (where
+#: track tiles clamp short enough for (128, 512) to fit).  Candidates
+#: clamp to the operand shape, so an oversized entry is timed at most
+#: once (see the dedup in the sweep); a candidate whose clamped block
+#: overflows the kernel's VMEM budget at the chunk's width is dropped
+#: (:func:`candidates_for`).
 CANDIDATES: Tuple[Tuple[int, int], ...] = (
-    (32, 128), (64, 128), (64, 256), (128, 128), (128, 256),
-    (128, 512), (256, 256), (256, 512))
+    (8, 256), (8, 512), (8, 1024), (16, 256), (16, 512), (32, 128),
+    (32, 256), (64, 128), (128, 128), (128, 512))
 
-#: The fixed pre-autotune default the tuned shape is benchmarked against.
-DEFAULT_SHAPE: Tuple[int, int] = (128, 512)
+#: The fixed default the tuned shape is benchmarked against.
+DEFAULT_SHAPE: Tuple[int, int] = DEFAULT_BLOCK
 
 #: In-process winner cache: ``shape_key -> TunedShape``.
 _CACHE: Dict[tuple, "TunedShape"] = {}
@@ -108,6 +114,15 @@ def roofline_point(n: int, t: int, v: int, s: int, k: int,
     }
 
 
+def candidates_for(n: int, t: int, v: int, k: int,
+                   candidates: Sequence[Tuple[int, int]] = CANDIDATES
+                   ) -> Tuple[Tuple[int, int], ...]:
+    """The candidates that compile for an ``(n, t, v)`` chunk with ``k``
+    queries: those whose clamped block fits the kernel's VMEM budget."""
+    return tuple((be, bt) for be, bt in candidates
+                 if vmem_bytes(min(be, n), min(bt, t), v, k) <= VMEM_BUDGET)
+
+
 def _time_once(fn, repeats: int) -> float:
     """Best-of-``repeats`` wall milliseconds, after one untimed warmup
     call (compilation / trace caching)."""
@@ -133,8 +148,9 @@ def autotune_block_shapes(scalars, tracks, n_tracks, thresholds, *,
     Candidates whose blocks clamp to the same effective ``(min(be, n),
     min(bt, t))`` are timed once — on small streaming chunks the sweep
     frequently collapses to a couple of distinct shapes, which is what
-    keeps autotune affordable mid-scan.  The fixed ``(128, 512)``
-    default is always included, so ``speedup_vs_default >= 1.0``."""
+    keeps autotune affordable mid-scan.  The fixed
+    :data:`DEFAULT_SHAPE` is always included, so
+    ``speedup_vs_default >= 1.0``."""
     import jax
     import jax.numpy as jnp
     from repro.kernels.event_filter import ops as ef_ops
@@ -154,7 +170,8 @@ def autotune_block_shapes(scalars, tracks, n_tracks, thresholds, *,
     thresholds = jnp.asarray(thresholds)
 
     effective: Dict[Tuple[int, int], Tuple[int, int]] = {}
-    for be, bt in tuple(candidates) + (DEFAULT_SHAPE,):
+    for be, bt in candidates_for(n, t, v, k, tuple(candidates)) \
+            + (DEFAULT_SHAPE,):
         effective.setdefault((min(be, n), min(bt, t)), (be, bt))
 
     def run(be, bt):

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 
 import json
 
+from repro.configs.geps_events import WIDTHS
 from repro.configs.registry import get_config, list_archs, reduced_config
 from repro.launch.mesh import make_mesh_of, make_production_mesh
 from repro.models import model_zoo
@@ -143,18 +144,14 @@ def serve_fleet(args):
     Reports fleet-aggregate hit rates (incl. the shared-L2 contribution),
     the gossip propagation bound, registry pre-warming, and — with
     ``--stream`` — a cross-frontend proxy read of one sample ticket."""
-    from repro.configs.geps_events import reduced as geps_reduced
-    from repro.core import events as ev
-    from repro.core.brick import create_store
+    from repro.configs.geps_events import by_width
+    from repro.core.brick import store_from_config
     from repro.fabric import Fleet, FragmentRegistry, MessageBus
     from repro.obs import flight as flight_lib
 
-    cfg = geps_reduced()
-    schema = ev.EventSchema.from_config(cfg)
-    store = create_store(schema, n_events=args.n_events,
-                         n_nodes=args.n_nodes,
-                         events_per_brick=cfg.events_per_brick,
-                         replication=cfg.replication_factor, seed=0)
+    cfg = by_width(args.width)
+    store = store_from_config(cfg, n_events=args.n_events,
+                              n_nodes=args.n_nodes, seed=0)
     want_obs = bool(args.trace_out or args.metrics_dump or args.policy)
     bus = MessageBus(drop_rate=args.drop_rate, seed=args.bus_seed)
     recorder = None
@@ -163,7 +160,8 @@ def serve_fleet(args):
         # (python -m repro.obs.replay) rebuilds an equal store from it
         recorder = flight_lib.FlightRecorder()
         recorder.record("store_config", origin="serve",
-                        schema_name="geps_reduced", n_events=args.n_events,
+                        schema_name=f"geps_{args.width}",
+                        n_events=args.n_events,
                         n_nodes=args.n_nodes,
                         events_per_brick=cfg.events_per_brick,
                         replication=cfg.replication_factor, seed=0)
@@ -261,17 +259,12 @@ def serve_queries(args):
     enables per-tenant cost-budgeted admission (planner cost units).
     ``--stream`` turns every submission into a streamed ticket and reports
     progressive-delivery metrics (time-to-first-partial vs final)."""
-    from repro.configs.geps_events import reduced as geps_reduced
-    from repro.core import events as ev
-    from repro.core.brick import create_store
+    from repro.configs.geps_events import by_width
+    from repro.core.brick import store_from_config
     from repro.service import QueryScheduler, QueryService, WindowController
 
-    cfg = geps_reduced()
-    schema = ev.EventSchema.from_config(cfg)
-    store = create_store(schema, n_events=args.n_events,
-                         n_nodes=args.n_nodes,
-                         events_per_brick=cfg.events_per_brick,
-                         replication=cfg.replication_factor, seed=0)
+    store = store_from_config(by_width(args.width), n_events=args.n_events,
+                              n_nodes=args.n_nodes, seed=0)
     sched = QueryScheduler(
         max_batch=args.window,
         cost_budget_per_tenant=args.cost_budget)
@@ -390,6 +383,11 @@ def main(argv=None):
     ap.add_argument("--production-mesh", action="store_true")
     # query mode
     ap.add_argument("--n-events", type=int, default=1024)
+    ap.add_argument("--width", choices=WIDTHS, default="reduced",
+                    help="query mode: event width from "
+                         "configs/geps_events.py — the reduced test width "
+                         "or the paper's ~1 MB event (64 scalars, "
+                         "4096 x 63 tracks)")
     ap.add_argument("--n-nodes", type=int, default=4)
     ap.add_argument("--tenants", type=int, default=4)
     ap.add_argument("--queries", type=int, default=64)
@@ -477,6 +475,8 @@ def main(argv=None):
                          "third of the way through the workload "
                          "(failover + liveness gossip)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.mode == "query":
         if args.fleet > 1:

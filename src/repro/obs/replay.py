@@ -115,13 +115,14 @@ def _build_store(records):
             "log has no store_config record (programmatic recording): "
             "pass store= with a freshly built store equal to the "
             "original run's")
-    if sc.get("schema_name") != "geps_reduced":
-        raise ReplayError(
-            f"unknown store schema {sc.get('schema_name')!r}")
-    from repro.configs.geps_events import reduced as geps_reduced
+    from repro.configs.geps_events import WIDTHS, by_width
     from repro.core import events as ev
     from repro.core.brick import create_store
-    schema = ev.EventSchema.from_config(geps_reduced())
+    name = sc.get("schema_name", "")
+    width = name[len("geps_"):] if name.startswith("geps_") else None
+    if width not in WIDTHS:
+        raise ReplayError(f"unknown store schema {name!r}")
+    schema = ev.EventSchema.from_config(by_width(width))
     return create_store(schema, n_events=sc["n_events"],
                         n_nodes=sc["n_nodes"],
                         events_per_brick=sc["events_per_brick"],

@@ -441,6 +441,20 @@ def test_spmd_mesh_lockstep_emulation_bit_identical():
     assert times == sorted(times)
 
 
+def test_spmd_mesh_wider_than_accelerator_devices_raises(monkeypatch):
+    """Lockstep emulation is a CPU-only stand-in: on an accelerator a
+    mesh wider than the devices fails instead of reporting made-up
+    times."""
+    import jax
+    store = make_store(n_events=32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = SpmdBackend(MetadataCatalog(store.n_nodes), store,
+                       chunk_events=16, use_pallas=True,
+                       mesh_devices=len(jax.devices()) + 1)
+    with pytest.raises(ValueError, match="mesh_devices"):
+        mesh._mesh_is_real()
+
+
 def test_spmd_real_mesh_shard_map_bit_identical():
     """With enough physical devices, mesh groups execute as ONE
     shard_map call over stacked padded sub-chunks — and partials stay

@@ -74,6 +74,23 @@ def test_brick_partition_covers_all_events():
     np.testing.assert_array_equal(ids, np.arange(100))
 
 
+def test_store_from_config_takes_the_named_width():
+    from repro.configs.geps_events import CONFIG, by_width
+    from repro.core.brick import store_from_config
+    assert by_width("paper") is CONFIG
+    with pytest.raises(ValueError, match="width"):
+        by_width("huge")
+    store = store_from_config(by_width("reduced"), n_events=40, n_nodes=4,
+                              seed=7)
+    same = make_store(n_events=40)
+    assert store.schema == SCHEMA
+    assert [s.replicas for s in store.specs.values()] == \
+        [s.replicas for s in same.specs.values()]
+    for bid, batch in same.bricks.items():
+        for key, col in batch.items():
+            np.testing.assert_array_equal(store.bricks[bid][key], col)
+
+
 def test_replica_placement_disjoint():
     for bid in range(16):
         node = bid % 5

@@ -1,6 +1,8 @@
 """Multi-device SPMD correctness, run in a subprocess with 8 fake CPU
 devices (the parent test process must keep its 1-device view for the other
-tests — jax pins device count at first init)."""
+tests — jax pins device count at first init).  The child is pinned to the
+CPU platform, so it never probes for an accelerator's runtime."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -19,7 +21,8 @@ def run_with_devices(body: str, n: int = 8) -> str:
     out = subprocess.run(
         [sys.executable, "-c", src],
         env={"PYTHONPATH": str(REPO / "src"), "PATH": "/usr/bin:/bin",
-             "HOME": "/root"},
+             "HOME": os.environ.get("HOME", str(REPO)),
+             "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=600, cwd=str(REPO))
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
     return out.stdout
