@@ -12,11 +12,9 @@ Reads a trace written by ``serve.py --trace-out out.jsonl`` (or any
   vocabulary per front-end: ``policy_transition`` (by edge),
   ``rereplicate`` (copies), ``lease_adopt`` and ``lease_fallback``;
 - the top-N slowest packets with their grid node, brick and size (the
-  straggler view the paper's operators would start from).
-
-Lease-export streams stamp their string lease key as the ``ticket`` of
-``stream_partial`` events; those rows sort after integer tickets and
-are otherwise reported verbatim.
+  straggler view the paper's operators would start from), and the wall
+  milliseconds of each packet's host phases: ``stage``, ``launch``,
+  ``wait`` (SPMD kernel chunks) and ``merge`` (streamed windows).
 
 Usage::
 
@@ -111,10 +109,28 @@ def ticket_breakdown(records):
     return rows
 
 
+#: a packet's host phases, as its child spans name them
+PHASES = ("stage", "launch", "wait", "merge")
+
+
+def wall_dur(rec) -> float:
+    t1 = rec.get("t1_wall")
+    return 0.0 if t1 is None else max(0.0, float(t1) - rec["t0_wall"])
+
+
 def slowest_packets(records, top):
+    """The ``top`` longest packets, each with the wall seconds of its
+    host phases (``PHASES``) summed from its child spans."""
     pkts = [r for r in records if r["name"] == "packet"]
     pkts.sort(key=span_dur, reverse=True)
-    return pkts[:top]
+    pkts = pkts[:top]
+    phases = {(p["process"], p["span_id"]): dict.fromkeys(PHASES, 0.0)
+              for p in pkts}
+    for rec in records:
+        got = phases.get((rec["process"], rec["parent_id"]))
+        if got is not None and rec["name"] in got:
+            got[rec["name"]] += wall_dur(rec)
+    return [(p, phases[(p["process"], p["span_id"])]) for p in pkts]
 
 
 def fleet_events(records):
@@ -179,14 +195,16 @@ def main(argv=None):
 
     pkts = slowest_packets(records, args.top)
     if pkts:
-        print(f"\ntop {len(pkts)} slowest packets:")
+        print(f"\ntop {len(pkts)} slowest packets "
+              f"(phases in wall milliseconds):")
         print(f"{'dur_s':>9} {'fe':>5} {'node':>5} {'brick':>6} "
-              f"{'events':>7}")
-        for p in pkts:
+              f"{'events':>7}" + "".join(f" {n:>8}" for n in PHASES))
+        for p, ph in pkts:
             a = p["attrs"]
             print(f"{span_dur(p):9.4f} {p['process']:>5} "
                   f"{a.get('node', '-'):>5} {a.get('brick', '-'):>6} "
-                  f"{a.get('size', '-'):>7}")
+                  f"{a.get('size', '-'):>7}"
+                  + "".join(f" {1e3 * ph[n]:8.3f}" for n in PHASES))
     return 0
 
 

@@ -516,30 +516,53 @@ class SpmdBackend:
         return tuned.block_e, tuned.block_t
 
     # ------------------------------------------------------------------ #
+    def _phase(self, name: str, packet, **attrs):
+        """Open host phase ``name`` of the chunk whose ``packet`` span is
+        given.  The parent is passed, not taken from the tracer's stack:
+        double buffering finalizes chunk i after chunk i+1 is launched.
+        Phases are timed on the wall clock; on the virtual axis they sit
+        at their packet's start."""
+        return self.obs.tracer.begin(name, t_virtual=packet.t0_virtual,
+                                     parent=packet, seq=packet.attrs["seq"],
+                                     **attrs)
+
     def _dispatch_chunk(self, plan: query_lib.FragmentPlan,
                         split: PlanSplit, seq: int, brick_id: int,
                         start: int, size: int, owner: int,
                         calib_iters: int,
-                        block_shapes: Tuple[int, int]) -> _Inflight:
+                        block_shapes: Tuple[int, int],
+                        span=None) -> _Inflight:
         """Dispatch one chunk: kernel sub-batch + jnp sub-batch launched
         asynchronously (device values stay lazy), or — for windows with
         no kernel targets — the shared ``eval_plan_slice`` primitive
-        evaluated in place."""
+        evaluated in place.  ``span`` is the chunk's ``packet`` span
+        (None with tracing off); kernel chunks record their ``stage``
+        and ``launch`` phases under it."""
         infl = _Inflight(seq=seq, brick_id=brick_id, start=start,
-                         size=size, owner=owner)
+                         size=size, owner=owner, span=span)
         if not split.any_kernel:
             infl.res = eval_plan_slice(self.store, plan, brick_id, start,
                                        size, calib_iters)
             return infl
         import jax.numpy as jnp
         from repro.kernels.event_filter import ops as ef_ops
+        stage = None if span is None else self._phase("stage", span)
         batch = self.store.bricks[brick_id]
         sl = {k: v[start:start + size] for k, v in batch.items()}
         infl.ids = np.asarray(sl["event_id"])
+        scalars = jnp.asarray(sl["scalars"])
+        tracks = jnp.asarray(sl["tracks"])
+        n_tracks = jnp.asarray(sl["n_tracks"])
+        if stage is not None:
+            nbytes = int(sl["scalars"].nbytes + sl["tracks"].nbytes
+                         + sl["n_tracks"].nbytes)
+            stage.attrs["bytes"] = nbytes
+            self.obs.metrics.counter("spmd.h2d_bytes").inc(nbytes)
+            self.obs.tracer.end(stage)
+        launch = None if span is None else self._phase("launch", span)
         be, bt = block_shapes
         infl.mask_dev, infl.var_dev = ef_ops.event_filter_batch(
-            jnp.asarray(sl["scalars"]), jnp.asarray(sl["tracks"]),
-            jnp.asarray(sl["n_tracks"]), split.thresholds,
+            scalars, tracks, n_tracks, split.thresholds,
             var_idx=split.var_idx, calib_iters=calib_iters,
             interpret=self.interpret, block_e=be, block_t=bt)
         if split.jnp_cols:
@@ -555,25 +578,33 @@ class SpmdBackend:
             infl.jnp_masks = [
                 query_lib.eval_node(t, slj, self.store.schema, False, memo)
                 for t in split.jnp_targets]
+        if launch is not None:
+            self.obs.tracer.end(launch)
         return infl
 
     def _dispatch_group(self, plan: query_lib.FragmentPlan,
                         split: PlanSplit,
                         slots: List[Tuple[int, int, int]], brick_id: int,
                         owner: int, calib_iters: int,
-                        block_shapes: Tuple[int, int]) -> List[_Inflight]:
+                        block_shapes: Tuple[int, int],
+                        spans: Optional[list] = None) -> List[_Inflight]:
         """Dispatch one mesh group — up to ``mesh_devices`` chunk slots
         of one brick — as a single ``shard_map`` kernel call over the
         stacked, zero-padded ``(D, n_max, ...)`` slabs (each device owns
         one sub-chunk).  Partials are still sliced back out per slot, so
         packetization — and therefore prefix bit-identity — is unchanged
         by the group width.  jnp sub-batch targets (mixed windows) run
-        per slot on the host path as usual."""
+        per slot on the host path as usual.  ``spans`` are the slots'
+        ``packet`` spans (None with tracing off); the group's ``stage``
+        and ``launch`` phases go under the first."""
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.kernels import resolve_interpret
         from repro.kernels.event_filter import ops as ef_ops
+        first = None if spans is None else spans[0]
+        stage = None if first is None else self._phase(
+            "stage", first, chunks=len(slots))
         batch = self.store.bricks[brick_id]
         n_max = max(size for _, _, size in slots)
         d = self.mesh_devices
@@ -591,20 +622,30 @@ class SpmdBackend:
             rows = [slab(key, start, size) for _, start, size in slots]
             while len(rows) < d:    # tail group: replicate a dummy slab
                 rows.append(np.zeros_like(rows[0]))
-            # each slab goes straight to the device that owns it
-            return jax.device_put(np.stack(rows), per_device)
+            return np.stack(rows)
 
+        host = [stacked(k) for k in ("scalars", "tracks", "n_tracks")]
+        # each slab goes straight to the device that owns it
+        scalars, tracks, n_tracks = (jax.device_put(a, per_device)
+                                     for a in host)
+        if stage is not None:
+            nbytes = int(sum(a.nbytes for a in host))
+            stage.attrs["bytes"] = nbytes
+            self.obs.metrics.counter("spmd.h2d_bytes").inc(nbytes)
+            self.obs.tracer.end(stage)
+        launch = None if first is None else self._phase(
+            "launch", first, chunks=len(slots))
         be, bt = block_shapes
         fn = ef_ops.sharded_event_filter_batch(
             mesh, var_idx=split.var_idx, calib_iters=calib_iters,
             interpret=resolve_interpret(self.interpret), block_e=be,
             block_t=bt)
-        gmask, gvar = fn(stacked("scalars"), stacked("tracks"),
-                         stacked("n_tracks"), split.thresholds)
+        gmask, gvar = fn(scalars, tracks, n_tracks, split.thresholds)
         out: List[_Inflight] = []
         for i, (seq, start, size) in enumerate(slots):
             infl = _Inflight(seq=seq, brick_id=brick_id, start=start,
-                             size=size, owner=owner)
+                             size=size, owner=owner,
+                             span=None if spans is None else spans[i])
             infl.ids = np.asarray(batch["event_id"][start:start + size])
             infl.mask_dev = gmask[i, :size]
             infl.var_dev = gvar[i, :size]
@@ -620,6 +661,8 @@ class SpmdBackend:
                                         memo)
                     for t in split.jnp_targets]
             out.append(infl)
+        if launch is not None:
+            self.obs.tracer.end(launch)
         return out
 
     def _finalize_chunk(self, infl: _Inflight,
@@ -629,16 +672,20 @@ class SpmdBackend:
         their original target slots)."""
         if infl.res is not None:
             return infl.res
+        # the device->host reads, where the host blocks on the device
+        wait = None if infl.span is None else self._phase("wait", infl.span)
         mask = np.asarray(infl.mask_dev)   # (size, K_kernel)
         var = np.asarray(infl.var_dev)
+        jnp_masks = ([np.asarray(m) for m in infl.jnp_masks]
+                     if infl.jnp_masks is not None else ())
+        if wait is not None:
+            self.obs.tracer.end(wait)
         n_targets = len(split.kernel_cols) + len(split.jnp_cols)
         out: List[Optional[merge_lib.QueryResult]] = [None] * n_targets
         for j, col in enumerate(split.kernel_cols):
             out[col] = merge_lib.from_mask(mask[:, j], var, infl.ids)
-        if infl.jnp_masks is not None:
-            for j, col in enumerate(split.jnp_cols):
-                out[col] = merge_lib.from_mask(
-                    np.asarray(infl.jnp_masks[j]), var, infl.ids)
+        for j, col in enumerate(split.jnp_cols):
+            out[col] = merge_lib.from_mask(jnp_masks[j], var, infl.ids)
         infl.res = out
         return out
 
@@ -686,9 +733,6 @@ class SpmdBackend:
         buffered = (self.double_buffer and split.any_kernel
                     and not lockstep and not mesh_fast)
 
-        if obs is not None:
-            obs.metrics.gauge("spmd.mesh_devices").set(mesh)
-
         results: List[List[merge_lib.QueryResult]] = []
         t_start = clock()
         t_lockstep = 0.0    # critical-path seconds (emulated mesh clock)
@@ -700,21 +744,17 @@ class SpmdBackend:
 
         def emit(infl: _Inflight, wall: float) -> None:
             """Record one finalized chunk: telemetry, obs, stats, and the
-            in-order partial emission."""
+            in-order partial emission (inside the chunk's packet span, so
+            the stream's ``merge`` span nests under it)."""
             res = infl.res
             stats.packet_telemetry.append(PacketTelemetry(
                 size=infl.size, calib_iters=rec.calib_iters,
                 n_aggregates=plan_aggs, wall_s=wall,
                 n_targets=len(plan.targets()), node=infl.owner))
             if obs is not None:
-                if infl.span is not None:
-                    obs.tracer.end(
-                        infl.span,
-                        t_virtual=obs.tracer.virtual_base + stamp())
                 obs.metrics.counter("packet.count").inc()
                 obs.metrics.histogram("packet.latency_s").observe(wall)
                 obs.metrics.histogram("packet.events").observe(infl.size)
-                obs.metrics.gauge("spmd.chunk_events").set(infl.size)
                 if split.any_kernel:
                     obs.metrics.counter("spmd.kernel_events").inc(
                         infl.size)
@@ -734,7 +774,10 @@ class SpmdBackend:
                 on_partial(PacketPartial(
                     seq=infl.seq, brick_id=infl.brick_id, start=infl.start,
                     size=infl.size, node=infl.owner, t_virtual=stamp(),
-                    failures=0, partials=res))
+                    failures=0, partials=res, span=infl.span))
+            if infl.span is not None:
+                obs.tracer.end(infl.span,
+                               t_virtual=obs.tracer.virtual_base + stamp())
 
         pending: Optional[_Inflight] = None
 
@@ -783,10 +826,17 @@ class SpmdBackend:
                         slots.append((seq, start, size))
                         seq += 1
                         start += size
+                    spans = None
+                    if obs is not None:
+                        spans = [obs.tracer.begin(
+                            "packet",
+                            t_virtual=obs.tracer.virtual_base + stamp(),
+                            seq=q, brick=bid, start=s0, size=sz, node=owner)
+                            for q, s0, sz in slots]
                     t0 = clock()
                     infls = self._dispatch_group(plan, split, slots, bid,
                                                  owner, rec.calib_iters,
-                                                 block_shapes)
+                                                 block_shapes, spans)
                     for infl in infls:
                         self._finalize_chunk(infl, split)
                     per = max(clock() - t0, 1e-9) / len(slots)
@@ -803,8 +853,7 @@ class SpmdBackend:
                         node=owner)
                 infl = self._dispatch_chunk(plan, split, seq, bid, start,
                                             size, owner, rec.calib_iters,
-                                            block_shapes)
-                infl.span = span
+                                            block_shapes, span)
                 if not buffered:
                     finalize(infl)
                 else:

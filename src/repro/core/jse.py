@@ -78,7 +78,9 @@ class PacketPartial:
     ``t_virtual`` is the packet's compute-completion time on the simulated
     grid clock (the same clock as ``JobStats.makespan_s``), and
     ``failures`` the cumulative node deaths observed so far (coverage
-    holes; see ``docs/streaming.md``)."""
+    holes; see ``docs/streaming.md``).  ``span`` is the packet's trace
+    span (None with tracing off): the stream's ``merge`` span goes under
+    it."""
     seq: int
     brick_id: int
     start: int
@@ -87,6 +89,8 @@ class PacketPartial:
     t_virtual: float
     failures: int
     partials: List[merge_lib.QueryResult]
+    span: object = dataclasses.field(default=None, compare=False,
+                                     repr=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -588,13 +592,14 @@ class JobSubmissionEngine:
                     emit_buf[seq] = PacketPartial(
                         seq=seq, brick_id=pkt.brick_id, start=pkt.start,
                         size=pkt.size, node=node, t_virtual=now + dur,
-                        failures=stats.failures, partials=res)
+                        failures=stats.failures, partials=res,
+                        span=pkt_span)
             elif on_partial is not None:
                 on_partial(PacketPartial(
                     seq=seq, brick_id=pkt.brick_id,
                     start=pkt.start, size=pkt.size, node=node,
                     t_virtual=now + dur, failures=stats.failures,
-                    partials=res))
+                    partials=res, span=pkt_span))
             # throughput telemetry sees compute only — staging/dispatch in
             # the EMA would shrink every node's packets (GRIS reports CPU
             # rate, not control-plane latency)

@@ -13,7 +13,9 @@ whole stack reports into:
   ``attrs``.
 * A :class:`Tracer` is the per-process collector.  Callers pass virtual
   timestamps explicitly (every layer has its own notion of virtual time);
-  wall stamps are taken automatically from ``time.perf_counter``.  A
+  wall stamps are taken automatically from ``time.perf_counter``, counted
+  from the wall-clock instant :attr:`Tracer.origin_ns`, so a profiler
+  trace can place them on its own clock (:meth:`Tracer.wall_spans`).  A
   parent *stack* (:meth:`Tracer.push`/:meth:`Tracer.pop`) lets an outer
   layer (the front-end's dispatch span) become the implicit parent of
   spans opened deeper in the stack (the engine's per-packet scans) without
@@ -39,10 +41,9 @@ SCHEMA_VERSION = 1
 
 # span taxonomy used by the instrumented layers (docs/observability.md)
 SPAN_NAMES = (
-    "submit", "admit", "cache_probe", "window", "plan", "dispatch",
-    "packet", "merge_prefix", "stream_partial", "stream", "final",
-    "node_death", "policy_transition", "speculate", "rereplicate",
-    "lease_adopt", "lease_fallback",
+    "submit", "window", "plan", "dispatch", "packet", "stage", "launch",
+    "wait", "merge", "stream", "final", "node_death", "policy_transition",
+    "speculate", "rereplicate", "lease_adopt", "lease_fallback",
 )
 
 STATUS_OPEN, STATUS_OK, STATUS_ERROR = "open", "ok", "error"
@@ -129,6 +130,8 @@ class Tracer:
         self._next_id = 0
         self._stack: List[Span] = []
         self._wall0 = time.perf_counter()
+        #: wall-clock instant (``time.time_ns``) of the wall stamps' zero
+        self.origin_ns = time.time_ns()
 
     # ------------------------------------------------------------------ #
     def _wall(self) -> float:
@@ -188,6 +191,18 @@ class Tracer:
         """Spans never closed — must be empty after a clean drain."""
         return [s for s in self.spans if s.status == STATUS_OPEN]
 
+    def wall_spans(self, names: Optional[Iterable[str]] = None
+                   ) -> List[Tuple[str, float, float]]:
+        """Closed spans (not events) as ``(name, start_s, end_s)``, in
+        seconds from :attr:`origin_ns`; only those named in ``names``
+        when given.  Shift by ``(origin_ns - t0_ns) * 1e-9`` to count from
+        another wall-clock instant ``t0_ns``, such as a profiler trace's
+        ``profile_start_time``."""
+        keep = None if names is None else set(names)
+        return [(s.name, s.t0_wall, s.t1_wall) for s in self.spans
+                if s.kind == "span" and s.t1_wall is not None
+                and (keep is None or s.name in keep)]
+
     # ------------------------------- export --------------------------- #
     def records(self) -> List[Dict[str, Any]]:
         """Every span as a schema-versioned record, in open order."""
@@ -198,8 +213,9 @@ class Tracer:
         save_jsonl(self.records(), path)
 
     def chrome_trace(self) -> Dict[str, Any]:
-        """This tracer's records as Chrome-trace JSON (dict)."""
-        return chrome_from_records(self.records())
+        """This tracer's records as Chrome-trace JSON (dict), with
+        :attr:`origin_ns` in ``otherData``."""
+        return chrome_from_records(self.records(), origin_ns=self.origin_ns)
 
     def save_chrome(self, path):
         """Write this tracer's records as a Chrome-trace file."""
@@ -288,13 +304,15 @@ def comparable_records(records: Sequence[Dict[str, Any]], *,
     return out
 
 
-def chrome_from_records(records: Sequence[Dict[str, Any]]
-                        ) -> Dict[str, Any]:
+def chrome_from_records(records: Sequence[Dict[str, Any]],
+                        origin_ns: Optional[int] = None) -> Dict[str, Any]:
     """Records -> Chrome-trace JSON (the ``traceEvents`` format Perfetto
     and ``chrome://tracing`` load).  Spans map to complete ("X") events
     and instantaneous marks to "i" events, on the *virtual* time axis
     (microseconds); ``pid`` is the emitting process and ``tid`` groups by
-    grid node when known, else by ticket."""
+    grid node when known, else by ticket.  ``origin_ns``, the wall-clock
+    instant the records' wall stamps count from, goes into ``otherData``
+    when given."""
     events: List[Dict[str, Any]] = []
     for rec in records:
         t0 = float(rec["t0_virtual"]) * 1e6
@@ -316,5 +334,8 @@ def chrome_from_records(records: Sequence[Dict[str, Any]]
             t1 = rec["t1_virtual"]
             dur = 0.0 if t1 is None else max(0.0, float(t1) * 1e6 - t0)
             events.append({**base, "ph": "X", "dur": dur})
+    other: Dict[str, Any] = {"schema": SCHEMA_VERSION}
+    if origin_ns is not None:
+        other["origin_ns"] = int(origin_ns)
     return {"traceEvents": events, "displayTimeUnit": "ms",
-            "otherData": {"schema": SCHEMA_VERSION}}
+            "otherData": other}

@@ -226,11 +226,13 @@ class WindowStreamPublisher:
         self._failures = pp.failures
         self._t = max(self._t, pp.t_virtual)
         obs = self.obs
+        span = None
         if obs is not None:
-            obs.tracer.event(
-                "merge_prefix",
-                t_virtual=obs.tracer.virtual_base + self._t,
-                seq=pp.seq, brick=pp.brick_id)
+            # the fold and fan-out of this packet, under its packet span
+            span = obs.tracer.begin(
+                "merge", t_virtual=obs.tracer.virtual_base + self._t,
+                parent=pp.span, seq=pp.seq, brick=pp.brick_id)
+        published = conflated = 0
         for col, acc in enumerate(self._accs):
             if acc is None:
                 continue
@@ -240,26 +242,22 @@ class WindowStreamPublisher:
             snap = StreamSnapshot(seq=pp.seq, result=acc.snapshot(),
                                   coverage=acc.coverage(),
                                   t_virtual=self._t)
-            if obs is None:
+            if span is None:
                 for stream in self.column_streams[col]:
                     stream.publish(snap)
             else:
                 for stream in self.column_streams[col]:
                     d0 = stream.dropped
                     stream.publish(snap)
-                    obs.metrics.counter("stream.published").inc()
-                    if stream.dropped > d0:
-                        # backpressure conflated an older snapshot away
-                        obs.metrics.counter("stream.conflated").inc(
-                            stream.dropped - d0)
-                    # lease-export streams carry their string lease key
-                    # as ticket_id; the span schema types ticket as
-                    # int|str|None, so the key is stamped directly
-                    obs.tracer.event(
-                        "stream_partial",
-                        t_virtual=obs.tracer.virtual_base + self._t,
-                        ticket=stream.ticket_id,
-                        seq=pp.seq, col=col)
+                    published += 1
+                    # backpressure conflated an older snapshot away
+                    conflated += stream.dropped - d0
+        if span is not None:
+            obs.metrics.counter("stream.published").inc(published)
+            if conflated:
+                obs.metrics.counter("stream.conflated").inc(conflated)
+            span.attrs.update(published=published, conflated=conflated)
+            obs.tracer.end(span)
 
     def finish(self, merged: Sequence[merge_lib.QueryResult],
                makespan_s: float) -> None:

@@ -167,8 +167,8 @@ def test_prom_text_exposition():
 
 def test_trace_schema_accepts_lease_key_ticket():
     tr = trace_lib.Tracer(process="fe0")
-    tr.event("stream_partial", ticket="lease:(e_total > 40.0)|c0|",
-             seq=1, col=0)
+    tr.event("lease_adopt", ticket="lease:(e_total > 40.0)|c0|",
+             owner="fe1")
     tr.event("final", ticket=7, outcome="SERVED")
     records = tr.records()
     assert not trace_lib.validate_records(records)

@@ -122,6 +122,64 @@ def test_jsonl_roundtrip_and_chrome_export(tmp_path):
     assert pkt["dur"] == pytest.approx(1.0e6)
 
 
+def test_tracer_origin_and_wall_spans():
+    tr = Tracer(process="fe0")
+    w = tr.begin("window", t_virtual=0.0)
+    p = tr.begin("packet", t_virtual=0.0, parent=w, seq=0)
+    tr.end(p)
+    tr.event("final", ticket=0)
+    tr.begin("stream", ticket=0)            # still open: left out
+    tr.end(w)
+    spans = tr.wall_spans()
+    assert [n for n, _, _ in spans] == ["window", "packet"]
+    assert all(0.0 <= s <= e for _, s, e in spans)
+    assert tr.wall_spans(["packet"]) == spans[1:]
+    # the anchor rides the Chrome export, never the v1 records
+    assert tr.chrome_trace()["otherData"]["origin_ns"] == tr.origin_ns
+    assert "origin_ns" not in chrome_from_records(tr.records())["otherData"]
+    assert all("origin_ns" not in r for r in tr.records())
+
+
+def test_tracer_spans_land_on_the_profilers_clock(tmp_path):
+    """A tracer span and a profiler annotation around the same block lie
+    within a millisecond of each other once the span is shifted from the
+    tracer's ``origin_ns`` to the trace's ``profile_start_time``."""
+    import glob
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda x: jnp.tanh(x).sum())
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    tr = Tracer(process="fe0")
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    time.sleep(0.02)
+    span = tr.begin("stage")
+    with jax.profiler.TraceAnnotation("annotated-stage"):
+        f(x).block_until_ready()
+        time.sleep(0.01)
+    tr.end(span)
+    jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    env, marks = {}, []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "Task Environment":
+            env = {k: v for k, v in plane.stats}
+        elif plane.name.startswith("/host:"):
+            marks += [(e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
+                      for line in plane.lines for e in line.events
+                      if e.name == "annotated-stage"]
+    (s0, e0), = marks
+    shift = (tr.origin_ns - int(env["profile_start_time"])) * 1e-9
+    (_, s1, e1), = tr.wall_spans(["stage"])
+    assert s1 + shift == pytest.approx(s0, abs=1e-3)
+    assert e1 + shift == pytest.approx(e0, abs=1e-3)
+
+
 # ----------------------------- metrics --------------------------------- #
 def test_histogram_buckets_and_registry_errors():
     reg = MetricsRegistry(origin="fe0")
@@ -367,6 +425,124 @@ def test_sim_and_spmd_ticket_spans_identical():
         assert validate_records(obs.tracer.records()) == []
         views.append(_ticket_view(obs))
     assert views[0] == views[1]
+
+
+SPMD_KW = {"use_pallas": True, "chunk_events": 16}
+
+
+def _spmd_run(obs, double_buffer):
+    store = make_store(seed=19)
+    svc = QueryService(store, backend="spmd", obs=obs,
+                       backend_kwargs=dict(SPMD_KW,
+                                           double_buffer=double_buffer))
+    tids = [svc.submit(e, tenant=f"t{i % 2}", stream=True)
+            for i, e in enumerate(EXPRS)]
+    svc.drain()
+    svc.close()
+    return store, svc, tids
+
+
+@pytest.mark.parametrize("double_buffer", [True, False])
+def test_spmd_scan_phase_spans(double_buffer):
+    """Each kernel chunk's host work lies in exactly one ``stage``,
+    ``launch``, ``wait`` and ``merge`` span under its ``packet`` span
+    (double buffering interleaves chunks, so the parent is explicit);
+    ``spmd.h2d_bytes`` counts the chunk inputs sent to the device; the
+    finals are those of the untraced run."""
+    from repro.obs import SPAN_NAMES
+    obs = Observability(origin="fe0")
+    store, svc, tids = _spmd_run(obs, double_buffer)
+    recs = obs.tracer.records()
+    assert validate_records(recs) == []
+    assert {r["name"] for r in recs} <= set(SPAN_NAMES)
+    packets = {r["span_id"]: r for r in recs if r["name"] == "packet"}
+    assert len(packets) == obs.metrics.value("packet.count") > 0
+    phases = {pid: {} for pid in packets}
+    for r in recs:
+        if r["name"] in ("stage", "launch", "wait", "merge"):
+            kids = phases[r["parent_id"]]
+            kids[r["name"]] = kids.get(r["name"], 0) + 1
+            assert r["attrs"]["seq"] == packets[r["parent_id"]]["attrs"]["seq"]
+            p = packets[r["parent_id"]]
+            assert p["t0_wall"] <= r["t0_wall"] <= r["t1_wall"] <= p["t1_wall"]
+    assert all(k == {"stage": 1, "launch": 1, "wait": 1, "merge": 1}
+               for k in phases.values())
+    per_window = sum(int(b[k].nbytes) for b in store.bricks.values()
+                     for k in ("scalars", "tracks", "n_tracks"))
+    windows = obs.metrics.value("window.dispatched")
+    staged = sum(r["attrs"]["bytes"] for r in recs if r["name"] == "stage")
+    assert obs.metrics.value("spmd.h2d_bytes") == staged \
+        == windows * per_window > 0
+    merges = [r for r in recs if r["name"] == "merge"]
+    assert obs.metrics.value("stream.published") == sum(
+        r["attrs"]["published"] for r in merges) > 0
+
+    _, base, base_tids = _spmd_run(None, double_buffer)
+    for a, b in zip(tids, base_tids):
+        ta, tb = svc.result(a), base.result(b)
+        assert ta.status == tb.status == SERVED
+        assert merge_lib.results_identical(ta.result, tb.result)
+        fa, fb = svc.stream(a).latest(), base.stream(b).latest()
+        assert fa.final and fb.final
+        assert merge_lib.results_identical(fa.result, fb.result)
+
+
+def test_merge_span_on_the_simulated_backend():
+    """The simulated grid streams through the same publisher: one
+    ``merge`` span per packet, parented to it, and no kernel phases."""
+    obs = Observability(origin="fe0")
+    run_service(make_store(seed=23), obs=obs, stream=True)
+    recs = obs.tracer.records()
+    assert validate_records(recs) == []
+    packets = {r["span_id"] for r in recs if r["name"] == "packet"}
+    merges = [r for r in recs if r["name"] == "merge"]
+    assert len(merges) == len(packets) > 0
+    assert {r["parent_id"] for r in merges} == packets
+    assert not any(r["name"] in ("stage", "launch", "wait") for r in recs)
+    assert obs.metrics.value("spmd.h2d_bytes") == 0
+
+
+def test_real_mesh_scan_phase_spans():
+    """On a real two-device mesh each group stages and launches once,
+    under its first slot's packet span; every slot waits and merges
+    under its own."""
+    from tests.test_multidevice import run_with_devices
+    run_with_devices("""
+        from tests.test_obs import EXPRS, make_store
+        from repro.obs import Observability, validate_records
+        from repro.service import QueryService
+        obs = Observability(origin="fe0")
+        store = make_store(seed=19)
+        svc = QueryService(store, backend="spmd", obs=obs,
+                           backend_kwargs={"use_pallas": True,
+                                           "chunk_events": 8,
+                                           "mesh_devices": 2})
+        for e in EXPRS:
+            svc.submit(e, stream=True)
+        svc.drain()
+        svc.close()
+        assert svc.backend._mesh_is_real()
+        recs = obs.tracer.records()
+        assert validate_records(recs) == []
+        packets = {r["span_id"]: r for r in recs if r["name"] == "packet"}
+        count = {}
+        for r in recs:
+            if r["parent_id"] in packets:
+                key = (r["parent_id"], r["name"])
+                count[key] = count.get(key, 0) + 1
+        for pid in packets:
+            assert count[(pid, "wait")] == count[(pid, "merge")] == 1
+        stages = [r for r in recs if r["name"] == "stage"]
+        launches = [r for r in recs if r["name"] == "launch"]
+        assert [r["parent_id"] for r in stages] == \
+            [r["parent_id"] for r in launches]
+        assert all(r["parent_id"] in packets for r in stages)
+        assert sum(r["attrs"]["chunks"] for r in stages) == len(packets)
+        assert any(r["attrs"]["chunks"] == 2 for r in stages)
+        assert obs.metrics.value("spmd.h2d_bytes") == sum(
+            r["attrs"]["bytes"] for r in stages) > 0
+        print("OK")
+    """, n=2)
 
 
 def test_scheduler_health_gate_narrows_windows():
