@@ -41,8 +41,8 @@ SCHEMA_VERSION = 1
 
 # span taxonomy used by the instrumented layers (docs/observability.md)
 SPAN_NAMES = (
-    "submit", "window", "plan", "dispatch", "packet", "stage", "launch",
-    "wait", "merge", "stream", "final", "node_death", "policy_transition",
+    "submit", "window", "plan", "dispatch", "upload", "packet", "stage",
+    "launch", "wait", "merge", "stream", "final", "node_death", "policy_transition",
     "speculate", "rereplicate", "lease_adopt", "lease_fallback",
 )
 

@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs.geps_events import reduced
 from repro.core import events as ev
+from repro.core import backend as backend_lib
 from repro.core import merge as merge_lib
 from repro.core.backend import (ChunkController, SimulatedBackend,
                                 SpmdBackend, make_backend)
@@ -526,6 +527,113 @@ def test_service_backend_kwargs_thread_through():
     with pytest.raises(ValueError, match="pre-built instance"):
         QueryService(store, backend=spmd,
                      backend_kwargs=dict(chunk_events=8))
+
+
+# ----------------------- resident store --------------------------------- #
+def force_streaming(monkeypatch):
+    """Make every device report less free memory than any store needs."""
+    monkeypatch.setattr(backend_lib, "_device_room", lambda device: 0)
+
+
+@pytest.mark.parametrize("exprs", [POOL[:3], MIXED],
+                         ids=["kernel", "mixed"])
+@pytest.mark.parametrize("double_buffer", [True, False])
+def test_spmd_resident_matches_streamed(monkeypatch, exprs, double_buffer):
+    """Chunks sliced from the resident image give the per-chunk partials,
+    prefix snapshots, fragment results and finals of chunks streamed
+    from the host, bit for bit, in kernel and mixed windows."""
+    store = make_store(n_events=96)
+    # the six bricks upload in three landed groups of two
+    brick = sum(int(store.bricks[0][k].nbytes)
+                for k in ("scalars", "tracks", "n_tracks"))
+    monkeypatch.setattr(backend_lib, "UPLOAD_GROUP_BYTES", 2 * brick)
+
+    def backend():
+        # chunks of 6 over bricks of 16: starts inside a brick and a
+        # shorter tail chunk
+        return SpmdBackend(MetadataCatalog(store.n_nodes), store,
+                           chunk_events=6, use_pallas=True,
+                           double_buffer=double_buffer)
+
+    # streamed first: a backend built later over the same store would
+    # share the image the resident one holds
+    with monkeypatch.context() as m:
+        force_streaming(m)
+        streamed = backend()
+        out_streamed = run_window(streamed, store, exprs, calib=2)
+    assert streamed._image is None
+    resident = backend()
+    out_resident = run_window(resident, store, exprs, calib=2)
+    assert resident._image is not None
+    assert_window_equivalent(out_streamed, out_resident)
+    for col in range(len(exprs)):
+        acc_s, acc_r = (merge_lib.MergeAccumulator(),
+                        merge_lib.MergeAccumulator())
+        for ps, pr in zip(out_streamed[2], out_resident[2]):
+            acc_s.add(ps.partials[col], brick_id=ps.brick_id)
+            acc_r.add(pr.partials[col], brick_id=pr.brick_id)
+            assert merge_lib.results_identical(acc_s.snapshot(),
+                                               acc_r.snapshot())
+
+
+@pytest.mark.parametrize("resident", [True, False],
+                         ids=["resident", "streamed"])
+def test_spmd_h2d_bytes_over_three_windows(monkeypatch, resident):
+    """A resident store crosses to the device once: ``spmd.h2d_bytes`` is
+    the store's kernel inputs after three windows, and every chunk is
+    served from the image.  A store that does not fit streams them
+    every window, as before residency."""
+    from repro.obs import Observability
+    if not resident:
+        force_streaming(monkeypatch)
+    store = make_store(n_events=96)
+    obs = Observability()
+    svc = QueryService(store, backend="spmd", obs=obs,
+                       backend_kwargs=dict(use_pallas=True, chunk_events=16))
+    for expr in POOL[:3]:
+        tid = svc.submit(expr, calib_iters=2, stream=True)
+        svc.step()
+        assert svc.result(tid).status == "SERVED"
+    svc.close()
+    inputs = sum(int(b[k].nbytes) for b in store.bricks.values()
+                 for k in ("scalars", "tracks", "n_tracks"))
+    chunks = obs.metrics.value("packet.count")
+    assert obs.metrics.value("window.dispatched") == 3
+    uploads = [r for r in obs.tracer.records() if r["name"] == "upload"]
+    if resident:
+        assert obs.metrics.value("spmd.h2d_bytes") == inputs
+        assert obs.metrics.value("spmd.resident_chunks") == chunks > 0
+        assert obs.metrics.value("spmd.resident_bytes") >= inputs
+        assert [u["attrs"]["bytes"] for u in uploads] == [inputs]
+    else:
+        assert obs.metrics.value("spmd.h2d_bytes") == 3 * inputs
+        assert obs.metrics.value("spmd.resident_chunks") == 0
+        assert not uploads
+
+
+def test_fleet_frontends_share_one_image():
+    """Front-ends of a ``Fleet`` over one store scan one resident image,
+    and it is freed once both are gone."""
+    import gc
+    import weakref
+    from repro.fabric import Fleet
+    store = make_store(n_events=96)
+    fleet = Fleet(store, 2, backend="spmd",
+                  backend_kwargs=dict(use_pallas=True, chunk_events=16))
+    for i in range(2):
+        fleet.submit(POOL[i], frontend=i)
+    fleet.drain()
+    backends = [fe.service.backend for fe in fleet.frontends]
+    image = backends[0]._image
+    assert image is not None and backends[1]._image is image
+    assert list(backend_lib._IMAGES.values()).count(image) == 1
+    gone = weakref.ref(image)
+    fleet.close()
+    del fleet, backends, image
+    gc.collect()
+    assert gone() is None
+    assert all(img.store is not store
+               for img in backend_lib._IMAGES.values())
 
 
 # ----------------------- service integration ---------------------------- #

@@ -442,16 +442,26 @@ def _spmd_run(obs, double_buffer):
     return store, svc, tids
 
 
-@pytest.mark.parametrize("double_buffer", [True, False])
-def test_spmd_scan_phase_spans(double_buffer):
+@pytest.mark.parametrize("double_buffer, resident",
+                         [(True, True), (False, True), (True, False),
+                          (False, False)],
+                         ids=["True", "False", "True-streamed",
+                              "False-streamed"])
+def test_spmd_scan_phase_spans(monkeypatch, double_buffer, resident):
     """Each kernel chunk's host work lies in exactly one ``stage``,
     ``launch``, ``wait`` and ``merge`` span under its ``packet`` span
     (double buffering interleaves chunks, so the parent is explicit);
-    ``spmd.h2d_bytes`` counts the chunk inputs sent to the device; the
-    finals are those of the untraced run."""
+    ``spmd.h2d_bytes`` counts the inputs sent to the device: a resident
+    store's once, in the ``upload`` span, a streamed store's chunk by
+    chunk in every window; the finals are those of the untraced
+    (resident) run."""
+    from repro.core import backend as backend_lib
     from repro.obs import SPAN_NAMES
     obs = Observability(origin="fe0")
-    store, svc, tids = _spmd_run(obs, double_buffer)
+    with monkeypatch.context() as m:
+        if not resident:
+            m.setattr(backend_lib, "_device_room", lambda device: 0)
+        store, svc, tids = _spmd_run(obs, double_buffer)
     recs = obs.tracer.records()
     assert validate_records(recs) == []
     assert {r["name"] for r in recs} <= set(SPAN_NAMES)
@@ -471,8 +481,16 @@ def test_spmd_scan_phase_spans(double_buffer):
                      for k in ("scalars", "tracks", "n_tracks"))
     windows = obs.metrics.value("window.dispatched")
     staged = sum(r["attrs"]["bytes"] for r in recs if r["name"] == "stage")
-    assert obs.metrics.value("spmd.h2d_bytes") == staged \
-        == windows * per_window > 0
+    uploads = [r for r in recs if r["name"] == "upload"]
+    if resident:
+        assert staged == 0
+        assert [u["attrs"]["bytes"] for u in uploads] == [per_window]
+        assert obs.metrics.value("spmd.h2d_bytes") == per_window > 0
+        assert obs.metrics.value("spmd.resident_chunks") == len(packets)
+    else:
+        assert not uploads
+        assert obs.metrics.value("spmd.h2d_bytes") == staged \
+            == windows * per_window > 0
     merges = [r for r in recs if r["name"] == "merge"]
     assert obs.metrics.value("stream.published") == sum(
         r["attrs"]["published"] for r in merges) > 0

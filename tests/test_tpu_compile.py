@@ -124,3 +124,22 @@ def test_autotune_candidates_compile_at_paper_width(one_chip, block):
         block_e=block[0], block_t=block[1], interpret=False)
     _assert_kernel(jax.jit(fn).lower(*_operands("paper", one_chip),
                                      thr).compile())
+
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_resident_chunk_slice_compiles_for_v5e(one_chip, width):
+    """The resident store's per-chunk program: one brick's image sliced
+    at a traced start and reshaped to the kernel's chunk shape."""
+    from repro.core.backend import _take_chunk, _track_rows
+    from repro.core.events import EventSchema
+    n, s, t, v = WIDTHS[width]
+    brick = 4 * n
+    rows, lanes = _track_rows(EventSchema(s, t, v))
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    compiled = _take_chunk.lower(
+        sds((brick, s), jnp.float32), sds((brick * rows, lanes), jnp.float32),
+        sds((brick,), jnp.int32), sds((), jnp.int32), size=n,
+        event_shape=(t, v)).compile()
+    assert "dynamic-slice" in compiled.as_text()
