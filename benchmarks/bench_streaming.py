@@ -94,42 +94,6 @@ def run_streamed(store, exprs, *, ramp=None):
     }, [f.result for f in finals]
 
 
-def run_spmd_streamed(store, exprs, *, double_buffer):
-    """The same streamed window on the SPMD kernel-split scan path, with
-    host-side prefix merging either overlapped with device compute
-    (``double_buffer=True``, the default) or strictly serialized.  Used
-    to re-verify that double buffering never delays the first partial —
-    its whole point is overlapping the merge with the NEXT chunk."""
-    svc = QueryService(store, use_cache=False, backend="spmd",
-                       backend_kwargs=dict(use_pallas=True,
-                                           double_buffer=double_buffer,
-                                           chunk_events=64))
-    recorder = {"first": None, "snaps": 0}
-
-    def record(snap):
-        if recorder["first"] is None:
-            recorder["first"] = snap.t_virtual
-        recorder["snaps"] += 1
-
-    tids = [svc.submit(e, tenant=f"t{i}", stream=True)
-            for i, e in enumerate(exprs)]
-    svc.stream(tids[0]).subscribe(record)
-    t0 = time.perf_counter()
-    svc.step()
-    wall = time.perf_counter() - t0
-    finals = [svc.stream(t).latest() for t in tids]
-    assert all(f is not None and f.final for f in finals)
-    t_final = finals[0].t_virtual
-    return {
-        "queries": len(exprs),
-        "t_first_partial_s": round(recorder["first"], 4),
-        "t_final_s": round(t_final, 4),
-        "ratio": round(recorder["first"] / t_final, 4),
-        "snapshots": recorder["snaps"],
-        "wall_s": round(wall, 2),
-    }, [f.result for f in finals]
-
-
 def main():
     global N_EVENTS
     if smoke():
@@ -171,30 +135,6 @@ def main():
         print("stream-aware ramp: first partial "
               f"{rows['batch8_ramp']['t_first_partial_s']}s <= fixed "
               f"{rows['batch8']['t_first_partial_s']}s, OK")
-
-    # SPMD double-buffer leg: overlapping the host-side prefix merge with
-    # the next chunk's device compute must not delay the first partial
-    # (warm the kernel dispatch once, then measure both modes)
-    run_spmd_streamed(store, BATCH, double_buffer=True)
-    for name, buf in (("spmd_unbuffered", False), ("spmd_buffered", True)):
-        row, merged = run_spmd_streamed(store, BATCH, double_buffer=buf)
-        rows[name] = row
-        finals[name] = merged
-        print(f"{name},{row['queries']},{row['t_first_partial_s']},"
-              f"{row['t_final_s']},{row['ratio']},{row['snapshots']},"
-              f"{row['wall_s']}")
-    for got, ref in zip(finals["spmd_buffered"], finals["spmd_unbuffered"]):
-        assert results_identical(got, ref), \
-            "double buffering changed streamed finals"
-    if not smoke():
-        assert (rows["spmd_buffered"]["t_first_partial_s"]
-                <= rows["spmd_unbuffered"]["t_first_partial_s"] * 1.25
-                + 0.005), \
-            "double buffering regressed SPMD time-to-first-partial"
-        print("spmd double-buffer: first partial "
-              f"{rows['spmd_buffered']['t_first_partial_s']}s vs "
-              f"unbuffered {rows['spmd_unbuffered']['t_first_partial_s']}s "
-              "(no regress), OK")
 
     # bit-identity spot check: streamed finals == an independent batch run
     # merging only at job end (same store, fixed packets)

@@ -1,5 +1,4 @@
-"""Benchmark harness: one entry per paper table/figure + the roofline
-table.  Prints ``name,value,derived`` CSV blocks.
+"""Benchmark harness: one entry per paper table/figure.  Prints ``name,value,derived`` CSV blocks.
 
   crossover    - paper Fig 7 (single node vs grid-brick parallel)
   granularity  - paper section 6 packet-size effect
@@ -16,15 +15,11 @@ table.  Prints ``name,value,derived`` CSV blocks.
                  BENCH_streaming.json snapshot)
   fabric       - fleet shared-L2 hit rate, cross-frontend first-result
                  latency, registry pre-warming (BENCH_fabric.json)
-  backend      - unified execution backends: SPMD chunked streaming scan
-                 vs simulated grid (bit-identical results, wall-clock
-                 time-to-first-partial; BENCH_backend.json)
   query_spmd   - SPMD grid-brick query step micro-benchmark (real compute)
 
 Everything runs in this one process, and a failing phase fails the run.
-The LM dry-run tools (``benchmarks/perf_probe.py``,
-``benchmarks/bench_roofline.py``) lower LM cells on fake CPU devices and
-run on their own.
+These are CPU reproductions of the paper's claims, not speed: the chip
+benchmark is ``bench/`` (``BENCHMARK.json``).
 
 ``--smoke`` runs every bench in a tiny configuration with perf asserts
 and snapshot writes disabled — the CI job that keeps benchmarks from
@@ -83,10 +78,6 @@ def main(argv=None) -> None:
     _section("coherence fabric (fleet cache tier + registry)")
     from benchmarks import bench_fabric
     bench_fabric.main()
-
-    _section("execution backends (SPMD chunked streaming vs simulated)")
-    from benchmarks import bench_backend
-    bench_backend.main()
 
     import jax
     import jax.numpy as jnp

@@ -17,7 +17,7 @@
   (the packages tenants program against stay documented).
 - Contract coverage: every public top-level symbol of
   ``src/repro/core/backend.py`` and of the event_filter kernel surface
-  (``src/repro/kernels/event_filter/{ops,tune}.py``) must be mentioned
+  (``src/repro/kernels/event_filter/ops.py``) must be mentioned
   by name in ``docs/backends.md``, every public top-level symbol of the
   ``src/repro/obs`` modules in ``docs/observability.md``, and every
   public top-level symbol of ``src/repro/service/policy.py`` in
@@ -160,12 +160,11 @@ def _contract_doc_errors(sources, doc_rel):
 def check_backend_contract_doc():
     """Every public top-level name in core/backend.py — plus the
     event_filter kernel surface the SPMD backend programs against
-    (ops.py recognizers/kernel entry points, tune.py autotuner) — must
-    appear in docs/backends.md (see module docstring)."""
+    (ops.py recognizers/kernel entry points) — must appear in
+    docs/backends.md (see module docstring)."""
     return _contract_doc_errors(
         [ROOT / "src/repro/core/backend.py",
-         ROOT / "src/repro/kernels/event_filter/ops.py",
-         ROOT / "src/repro/kernels/event_filter/tune.py"],
+         ROOT / "src/repro/kernels/event_filter/ops.py"],
         "docs/backends.md")
 
 

@@ -27,9 +27,6 @@ calibration, window planning) works identically on both paths:
   are bit-identical to ``tree_merge`` of the same prefix, and a window
   executed with the same chunk boundaries on either backend produces
   bit-identical partial streams and final results.
-- :class:`ChunkController` — EWMA sizing for ``chunk_events`` from
-  measured per-chunk wall times (the PROOF-rule shape
-  ``WindowController`` uses for window widths, applied to chunks).
 - :class:`PlanSplit` — the mixed-window kernel/jnp split: plan targets
   inside the fused ``event_filter`` kernel's conjunctive family run as
   one kernel sub-batch per chunk, the rest through the jnp fragment
@@ -39,9 +36,8 @@ calibration, window planning) works identically on both paths:
 
 See ``docs/backends.md`` for the full contract (merge-order determinism,
 clock semantics, failure semantics, Pallas fragment fusion, and the
-performance-tuning knobs: block-shape autotune, adaptive chunk sizing,
-mesh sharding, interpret auto-detect, double buffering, the resident
-store).
+SPMD scan's knobs: chunk size and ramp, mesh sharding, interpret
+auto-detect, the pipelined finalize, the resident store).
 """
 from __future__ import annotations
 
@@ -180,80 +176,6 @@ class SimulatedBackend:
             rereplicated=rereplicated)
 
 
-class ChunkController:
-    """EWMA controller for the SPMD scan's ``chunk_events``.
-
-    The streaming sweet spot for chunk sizing mirrors the PROOF packet
-    rule the :class:`~repro.service.frontend.WindowController` applies to
-    window widths: a chunk should take about ``target_s`` seconds of
-    scan, so the proposal is ``clamp(round(rate * target_s), min_chunk,
-    max_chunk)`` where ``rate`` is an EWMA of measured events/second
-    over completed chunks.  Chunks too small drown the scan in per-chunk
-    dispatch/merge overhead; chunks too large starve the partial stream
-    (time-to-first-partial grows linearly in chunk size).
-
-    ``hysteresis`` is the same relative dead-band as the window
-    controller's: the held size only moves when the proposal differs
-    from it by more than ``hysteresis x current``, so a noisy rate
-    estimate doesn't re-chunk every packet (chunk-size churn also churns
-    kernel compilation caches, which are keyed on chunk shape).
-
-    Determinism: the controller is a pure function of the observation
-    sequence — drive it from an injectable clock
-    (``SpmdBackend(clock=...)``) and a fixed seed reproduces the exact
-    chunk boundaries, which is what keeps flight logs byte-identical
-    under adaptive sizing (see ``tests/test_backend.py``)."""
-
-    def __init__(self, *, initial: int = 64, min_chunk: int = 8,
-                 max_chunk: int = 4096, target_s: float = 0.02,
-                 alpha: float = 0.3, hysteresis: float = 0.25):
-        if not (0.0 < alpha <= 1.0):
-            raise ValueError("alpha must be in (0, 1]")
-        if not (1 <= min_chunk <= max_chunk):
-            raise ValueError("need 1 <= min_chunk <= max_chunk")
-        if target_s <= 0:
-            raise ValueError("target_s must be positive")
-        if hysteresis < 0.0:
-            raise ValueError("hysteresis must be >= 0")
-        self.initial = initial
-        self.min_chunk = min_chunk
-        self.max_chunk = max_chunk
-        self.target_s = target_s
-        self.alpha = alpha
-        self.hysteresis = hysteresis
-        self._rate: Optional[float] = None
-        self._held: Optional[int] = None
-
-    def observe(self, events: int, wall_s: float) -> None:
-        """Record one completed chunk: ``events`` swept in ``wall_s``
-        seconds (host-observed, same clock as the backend's)."""
-        if events <= 0 or wall_s <= 0:
-            return
-        rate = events / wall_s
-        self._rate = rate if self._rate is None else (
-            self.alpha * rate + (1 - self.alpha) * self._rate)
-
-    @property
-    def scan_rate(self) -> Optional[float]:
-        """Smoothed events/second, or None before the first chunk."""
-        return self._rate
-
-    def chunk(self) -> int:
-        """Chunk size for the next dispatch: the clamped ``rate *
-        target_s`` proposal, filtered through the hysteresis dead-band."""
-        if self._rate is None:
-            target = max(self.min_chunk,
-                         min(self.max_chunk, self.initial))
-        else:
-            target = max(self.min_chunk,
-                         min(self.max_chunk,
-                             int(round(self._rate * self.target_s))))
-        if self._held is None or \
-                abs(target - self._held) > self.hysteresis * self._held:
-            self._held = target
-        return self._held
-
-
 @dataclasses.dataclass(frozen=True)
 class PlanSplit:
     """The mixed-window kernel/jnp split of one fragment plan's targets.
@@ -283,8 +205,7 @@ class PlanSplit:
 
     @property
     def full_kernel(self) -> bool:
-        """True when EVERY target runs through the kernel (the
-        all-in-family case the pre-split fusion hook required)."""
+        """True when EVERY target runs through the kernel."""
         return bool(self.kernel_cols) and not self.jnp_cols
 
 
@@ -341,8 +262,8 @@ def _track_rows(schema) -> Tuple[int, int]:
 
 def _chunk_reserve(schema, size: int) -> int:
     """Device bytes the chunks in flight take beside a resident image:
-    for each of three full-track chunks (a mixed window's two
-    double-buffered chunks and its calibrated jnp copy), its sliced rows
+    for each of three full-track chunks (a mixed window's two pipelined
+    chunks and its calibrated jnp copy), its sliced rows
     plus the ``(size, T, V)`` copy with V padded to whole 128-lane tiles.
     A kernel-only window's plane chunks are far smaller."""
     padded = -(-schema.track_vars // 128) * 128
@@ -463,96 +384,63 @@ class SpmdBackend:
 
     Bricks play the role of mesh shards (data that never moves); the
     scan visits them in brick-id order and sweeps each in chunks of
-    ``chunk_events``.  Every chunk runs the SAME fragment-factored
-    evaluation primitive as the simulation
-    (:func:`~repro.core.jse.eval_plan_slice`), so unique fragments are
-    evaluated once per chunk and a chunk's partials are bit-identical to
-    the simulated backend's partials for the same slice.  Per-chunk
-    :class:`~repro.core.jse.PacketPartial`\\ s stream out through
-    ``on_partial`` in deterministic merge order, which is what makes
-    prefix snapshots (via :class:`~repro.core.merge.MergeAccumulator`)
-    bit-identical to ``tree_merge`` of the same prefix — the streaming
-    guarantee the simulated path already had, now on the SPMD path.
-
-    Differences from the simulation, by design:
+    ``chunk_events``, the first ones capped by the stream ramp
+    (``packet_ramp``, ``ramp_factor``).  Every chunk runs the SAME
+    fragment-factored evaluation as the simulation
+    (:func:`~repro.core.jse.eval_plan_slice`), so a chunk's partials are
+    bit-identical to the simulated backend's for the same slice.
+    Per-chunk :class:`~repro.core.jse.PacketPartial`\\ s stream out
+    through ``on_partial`` in merge order (brick id, then offset), so
+    prefix snapshots (:class:`~repro.core.merge.MergeAccumulator`) are
+    bit-identical to ``tree_merge`` of the same prefix.
 
     - **Clock**: ``t_virtual`` on emitted partials and
-      ``JobStats.makespan_s`` are seconds on the backend's injectable
-      ``clock`` (wall by default) since the window started.  With
-      ``mesh_devices > 1`` on fewer physical devices, the stamps switch
-      to the **lockstep mesh clock**: chunks are grouped ``mesh_devices``
-      at a time, each group's cost is the *maximum* of its measured
-      sub-chunk walls (all shards execute a group simultaneously on a
-      real mesh), and stamps/makespan accumulate those group maxima —
-      the critical-path time a D-device lockstep mesh would take for the
-      measured per-shard compute.  With enough physical jax devices the
-      group actually executes as one ``shard_map`` call and the clock is
-      plain wall again.
+      ``JobStats.makespan_s`` are seconds on the injectable ``clock``
+      (wall by default) since the window started.
     - **Failures**: shards are resident compute state, not remote disks;
-      ``failure_script`` is a simulated-grid concept and a non-empty one
-      raises ``ValueError`` rather than being silently ignored.
-    - **Pallas fusion** (``use_pallas=True``): every plan target —
-      per-query roots AND materialized boolean fragments — that matches
-      the fused ``event_filter`` kernel's conjunctive family runs in the
-      kernel epilogue in one track-streaming pass per chunk; the rest
-      run through the jnp fragment walk on the same resident slice and
-      the two sub-batches are reassembled in slot order
-      (:class:`PlanSplit`), so a single out-of-family target no longer
-      drops the whole window to pure jnp.  ``interpret=None``
-      auto-detects (compiled on TPU/GPU, interpreter on CPU);
-      ``autotune=True`` sweeps ``(block_e, block_t)`` per chunk shape
-      and caches the winner in-process
-      (``repro.kernels.event_filter.tune``).  Either way the per-chunk
-      telemetry (``PacketTelemetry``) is recorded, so
-      ``planner.fit_cost_weights`` calibrates from SPMD runs too.
-    - **Double buffering** (``double_buffer=True``, the default): chunk
-      ``i+1`` is dispatched before chunk ``i`` is finalized, so host-side
-      ``MergeAccumulator`` prefix merging and partial emission overlap
-      the device compute of the next chunk, and each kernel chunk's
-      device->host reads start at launch, so they land while the next
-      chunk is dispatched.  Merge order is unchanged
-      (finalize strictly follows dispatch order).  Disabled automatically
-      in emulated-mesh mode, where per-sub-chunk walls must be measured
-      in isolation for the lockstep clock to be honest.
-    - **Residency**: kernel chunks read one image of the store in device
-      memory, so the store crosses from the host once, not every window.
-      Per brick it holds ``scalars``, ``n_tracks``, ``tracks`` as
-      lane-dense rows (a ``(n, T, V)`` array would pad V to 128 lanes)
-      and the ``(n, T)`` ``pt`` plane, derived on the device from the
-      rows; ``event_id`` stays on the host.  The kernel reads only
-      ``pt``, so each kernel chunk is one jitted on-device slice of
-      ``scalars``, the plane and ``n_tracks``; only a window with jnp
-      targets also takes the chunk's full tracks (a slice and reshape to
-      ``(size, T, V)``) for its jnp sub-batch.  The
-      image is built at the first window with kernel targets, not at
-      construction, and that window waits for it: the bricks are copied
-      in scan order, in landed groups of :data:`UPLOAD_GROUP_BYTES` (a
-      scan overlapping the copies measured slower on a v5e).
-      Backends over the same store object on the same device share it,
-      and it is freed with the last of them.  It is built only where it
-      fits: its bytes, its planes' and the chunks in flight within the
-      bytes the device's ``memory_stats()`` reports free (a device that
-      reports no limit, such as the CPU, counts as room).  A store that
-      does not fit streams each chunk from the host bricks (a
-      kernel-only window sends the ``pt`` plane of the chunk, a mixed
-      window its full tracks), as do pure-jnp windows and the real-mesh
-      ``shard_map`` path (which sends the ``pt`` slabs).
-    - **Adaptive chunks** (``adaptive_chunks=True``): ``chunk_events``
-      becomes the :class:`ChunkController`'s initial value and
-      subsequent chunks are sized from measured per-chunk walls toward
-      ``chunk_target_s`` seconds each.  Off by default — fixed chunks
-      are what make matched-packetization bit-identity tests possible.
+      a non-empty ``failure_script`` raises ``ValueError``.
+    - **Pallas fusion** (``use_pallas=True``): plan targets inside the
+      fused ``event_filter`` kernel's conjunctive family run as one
+      kernel sub-batch per chunk, at the kernel's default block shape;
+      the rest run through the jnp fragment walk on the same slice, and
+      the two are reassembled in slot order (:class:`PlanSplit`).
+      ``interpret=None`` picks by platform (compiled on TPU/GPU,
+      interpreter on CPU).  Per-chunk ``PacketTelemetry`` is recorded
+      either way, so ``planner.fit_cost_weights`` calibrates from SPMD
+      runs too.
+    - **Pipeline**: a window with kernel targets finalizes chunk ``i``
+      (device->host reads, slot reassembly, partial emission) after
+      chunk ``i+1`` is dispatched, and each chunk's reads start at its
+      launch, so they land while the next chunk is dispatched.  A
+      pure-jnp chunk is already evaluated at dispatch and is finalized
+      there.  Merge order is dispatch order either way.
+    - **Mesh** (``mesh_devices=D > 1``): the kernel sub-batches of each
+      group of ``D`` consecutive chunks of a brick run as one
+      ``shard_map`` call, one chunk per device, streamed from the host
+      (:meth:`_dispatch_group`); partials are still emitted per chunk.
+      ``D`` wider than jax's devices raises ``ValueError``.
+    - **Residency**: single-device kernel chunks read one image of the
+      store in device memory, so the store crosses from the host once,
+      not every window.  Per brick it holds ``scalars``, ``n_tracks``,
+      ``tracks`` as lane-dense rows (:func:`_track_rows`) and the
+      ``(n, T)`` ``pt`` plane the kernel reads, derived on the device;
+      ``event_id`` stays on the host.  A kernel chunk is one jitted
+      on-device slice of ``scalars``, the plane and ``n_tracks``; a
+      window with jnp targets also takes the chunk's full tracks.  The
+      image is built at the first window with kernel targets, which
+      waits for it (copies in scan order, in landed groups of
+      :data:`UPLOAD_GROUP_BYTES`), and only where it fits in what the
+      device's ``memory_stats()`` reports free (no limit, as on the CPU,
+      counts as room).  Backends over one store object on one device
+      share it; it is freed with the last of them.  A store that does
+      not fit streams each chunk from the host bricks.
     """
 
     def __init__(self, catalog: MetadataCatalog, store: BrickStore, *,
                  chunk_events: int = 64, packet_ramp: Optional[int] = None,
                  ramp_factor: float = 2.0, use_pallas: bool = False,
                  interpret: Optional[bool] = None,
-                 autotune: bool = False,
                  mesh_devices: int = 1,
-                 adaptive_chunks: bool = False,
-                 chunk_target_s: float = 0.02,
-                 double_buffer: bool = True,
                  clock: Callable[[], float] = time.perf_counter):
         if chunk_events <= 0:
             raise ValueError("chunk_events must be positive")
@@ -569,11 +457,7 @@ class SpmdBackend:
         self.ramp_factor = ramp_factor
         self.use_pallas = use_pallas
         self.interpret = interpret
-        self.autotune = autotune
         self.mesh_devices = mesh_devices
-        self.adaptive_chunks = adaptive_chunks
-        self.chunk_target_s = chunk_target_s
-        self.double_buffer = double_buffer
         self.clock = clock
         self.cost_weights = None  # installed by the service after refits
         #: shards are resident compute state, not killable virtual nodes
@@ -583,8 +467,6 @@ class SpmdBackend:
         self.supports_routing_policy = False
         # observability plane (repro.obs.Observability); None = disabled
         self.obs = None
-        #: most recent autotune verdict (TunedShape) — bench reporting
-        self.last_autotune = None
         # resolved lazily on first run (jax pins its devices at first use)
         self._mesh_real: Optional[bool] = None
         self._mesh = None
@@ -594,14 +476,12 @@ class SpmdBackend:
         self._resident_decided = False
 
     # ------------------------------------------------------------------ #
-    def _chunk_size(self, seq: int, remaining: int, ramp: Optional[int],
-                    controller: Optional[ChunkController]) -> int:
-        """Size of chunk ``seq``: the configured chunk (or the adaptive
-        controller's proposal), capped early by the shared geometric
-        stream ramp (``core/packets.py``), clipped to the shard
-        remainder."""
-        size = controller.chunk() if controller is not None \
-            else self.chunk_events
+    def _chunk_size(self, seq: int, remaining: int,
+                    ramp: Optional[int]) -> int:
+        """Size of chunk ``seq``: ``chunk_events``, capped early by the
+        shared geometric stream ramp (``core/packets.py``), clipped to
+        the shard remainder."""
+        size = self.chunk_events
         if ramp is not None:
             cap = ramp_cap(seq, ramp, self.ramp_factor)
             if cap < size:
@@ -631,35 +511,20 @@ class SpmdBackend:
                          thresholds=thresholds, var_idx=var_idx,
                          jnp_targets=tuple(targets[i] for i in jcols))
 
-    def _fuse_plan(self, plan: query_lib.FragmentPlan):
-        """Back-compat fusion hook: the batched kernel params when EVERY
-        plan target is in-family, else None.  Mixed windows no longer
-        fall back wholesale — see :meth:`_split_plan` — but this remains
-        the cheap "fully fused?" probe tests and tools use."""
-        split = self._split_plan(plan)
-        return (split.thresholds, split.var_idx) if split.full_kernel \
-            else None
-
     # ------------------------------------------------------------------ #
     def _mesh_is_real(self) -> bool:
-        """True when jax actually has ``mesh_devices`` devices (the
-        ``shard_map`` fast path); False emulates the mesh with lockstep
-        critical-path accounting, which only the CPU backend may do: on
-        an accelerator a mesh wider than the devices raises instead of
-        reporting made-up times.  Resolved once — jax pins its device
-        count at first init."""
+        """True when the scan runs on a ``mesh_devices``-wide mesh (the
+        ``shard_map`` path).  A mesh wider than jax's devices raises
+        ``ValueError``.  Resolved once — jax pins its device count at
+        first init."""
         if self._mesh_real is None:
-            if self.mesh_devices <= 1:
-                self._mesh_real = False
-            else:
-                n_dev = len(jax.devices())
-                if n_dev < self.mesh_devices \
-                        and jax.default_backend() != "cpu":
-                    raise ValueError(
-                        f"mesh_devices={self.mesh_devices} but the "
-                        f"{jax.default_backend()} backend has {n_dev} "
-                        f"device(s)")
-                self._mesh_real = n_dev >= self.mesh_devices
+            n_dev = len(jax.devices())
+            if self.mesh_devices > n_dev:
+                raise ValueError(
+                    f"mesh_devices={self.mesh_devices} but the "
+                    f"{jax.default_backend()} backend has {n_dev} "
+                    f"device(s)")
+            self._mesh_real = self.mesh_devices > 1
         return self._mesh_real
 
     def _scan_mesh(self):
@@ -670,31 +535,6 @@ class SpmdBackend:
             self._mesh = Mesh(np.asarray(jax.devices()[:self.mesh_devices]),
                               ("scan",))
         return self._mesh
-
-    def _maybe_autotune(self, split: PlanSplit, brick_id: int,
-                        calib_iters: int) -> Tuple[int, int]:
-        """Resolve the kernel block shapes for this window: the in-process
-        autotune winner for the (chunk shape x K x calib) class when
-        ``autotune=True``, the fixed default otherwise."""
-        from repro.kernels.event_filter import tune as ef_tune
-        if not (self.autotune and split.any_kernel):
-            return ef_tune.DEFAULT_SHAPE
-        batch = self.store.bricks[brick_id]
-        n = min(self.chunk_events, batch["scalars"].shape[0])
-        import jax.numpy as jnp
-        tuned = ef_tune.autotune_block_shapes(
-            jnp.asarray(batch["scalars"][:n]),
-            jnp.asarray(batch["tracks"][:n, :, 0]),
-            jnp.asarray(batch["n_tracks"][:n]),
-            split.thresholds, var_idx=split.var_idx,
-            calib_iters=calib_iters, interpret=self.interpret)
-        self.last_autotune = tuned
-        if self.obs is not None:
-            self.obs.metrics.gauge("spmd.autotune.block_e").set(
-                tuned.block_e)
-            self.obs.metrics.gauge("spmd.autotune.block_t").set(
-                tuned.block_t)
-        return tuned.block_e, tuned.block_t
 
     def _adopt_image(self) -> None:
         """Find or build the store's resident image on the scan device,
@@ -710,8 +550,7 @@ class SpmdBackend:
         image = _IMAGES.get(key)
         if image is None or image.store is not self.store:
             largest = max(s.n_events for s in self.store.specs.values())
-            size = largest if self.adaptive_chunks \
-                else min(self.chunk_events, largest)
+            size = min(self.chunk_events, largest)
             room = _device_room(device)
             need = (_input_bytes(self.store) + _plane_bytes(self.store)
                     + _chunk_reserve(self.store.schema, size))
@@ -735,7 +574,7 @@ class SpmdBackend:
     def _phase(self, name: str, packet, **attrs):
         """Open host phase ``name`` of the chunk whose ``packet`` span is
         given.  The parent is passed, not taken from the tracer's stack:
-        double buffering finalizes chunk i after chunk i+1 is launched.
+        the pipeline finalizes chunk i after chunk i+1 is launched.
         Phases are timed on the wall clock; on the virtual axis they sit
         at their packet's start."""
         return self.obs.tracer.begin(name, t_virtual=packet.t0_virtual,
@@ -745,9 +584,7 @@ class SpmdBackend:
     def _dispatch_chunk(self, plan: query_lib.FragmentPlan,
                         split: PlanSplit, seq: int, brick_id: int,
                         start: int, size: int, owner: int,
-                        calib_iters: int,
-                        block_shapes: Tuple[int, int],
-                        span=None) -> _Inflight:
+                        calib_iters: int, span=None) -> _Inflight:
         """Dispatch one chunk: kernel sub-batch + jnp sub-batch launched
         asynchronously (device values stay lazy), or — for windows with
         no kernel targets — the shared ``eval_plan_slice`` primitive
@@ -796,11 +633,10 @@ class SpmdBackend:
                     metrics.counter("spmd.resident_chunks").inc()
             self.obs.tracer.end(stage)
         launch = None if span is None else self._phase("launch", span)
-        be, bt = block_shapes
         infl.mask_dev, infl.var_dev = ef_ops.event_filter_batch(
             scalars, pt, n_tracks, split.thresholds,
             var_idx=split.var_idx, calib_iters=calib_iters,
-            interpret=self.interpret, block_e=be, block_t=bt)
+            interpret=self.interpret)
         if split.jnp_cols:
             # out-of-family targets: the same shared-memo jnp walk the
             # plan runs, restricted to the jnp sub-batch (values are
@@ -815,9 +651,9 @@ class SpmdBackend:
             infl.jnp_masks = [
                 query_lib.eval_node(t, slj, self.store.schema, False, memo)
                 for t in split.jnp_targets]
-        # start the device->host reads now: with double buffering they
-        # land while the next chunk is dispatched, so `wait` finds them
-        # on the host instead of paying each read's round trip
+        # start the device->host reads now: they land while the next
+        # chunk is dispatched, so `wait` finds them on the host instead
+        # of paying each read's round trip
         for out in (infl.mask_dev, infl.var_dev, *(infl.jnp_masks or ())):
             if isinstance(out, jax.Array):
                 out.copy_to_host_async()
@@ -829,7 +665,6 @@ class SpmdBackend:
                         split: PlanSplit,
                         slots: List[Tuple[int, int, int]], brick_id: int,
                         owner: int, calib_iters: int,
-                        block_shapes: Tuple[int, int],
                         spans: Optional[list] = None) -> List[_Inflight]:
         """Dispatch one mesh group — up to ``mesh_devices`` chunk slots
         of one brick — as a single ``shard_map`` kernel call over the
@@ -880,11 +715,9 @@ class SpmdBackend:
             self.obs.tracer.end(stage)
         launch = None if first is None else self._phase(
             "launch", first, chunks=len(slots))
-        be, bt = block_shapes
         fn = ef_ops.sharded_event_filter_batch(
             mesh, var_idx=split.var_idx, calib_iters=calib_iters,
-            interpret=resolve_interpret(self.interpret), block_e=be,
-            block_t=bt)
+            interpret=resolve_interpret(self.interpret))
         gmask, gvar = fn(scalars, pt, n_tracks, split.thresholds)
         out: List[_Inflight] = []
         for i, (seq, start, size) in enumerate(slots):
@@ -958,36 +791,20 @@ class SpmdBackend:
         plan_aggs = query_lib.unique_aggregates(plan.targets())
         split = self._split_plan(plan)
         ramp = packet_ramp if packet_ramp is not None else self.packet_ramp
-        controller = (ChunkController(initial=self.chunk_events,
-                                      target_s=self.chunk_target_s)
-                      if self.adaptive_chunks else None)
-        bricks = sorted(rec.bricks)
-        block_shapes = (self._maybe_autotune(split, bricks[0],
-                                             rec.calib_iters)
-                        if bricks and self.use_pallas else None)
-        mesh = max(1, self.mesh_devices)
-        lockstep = mesh > 1 and not self._mesh_is_real()
-        # with enough physical devices AND kernel targets, whole groups
-        # execute as one shard_map call; otherwise (pure-jnp window on a
-        # real mesh) the scan degrades to the sequential stream path
-        mesh_fast = mesh > 1 and not lockstep and split.any_kernel
-        if split.any_kernel and not mesh_fast:
+        # a step dispatches one chunk, or on a real mesh one group of
+        # `mesh_devices` chunks as a single shard_map call; a pure-jnp
+        # window scans chunk by chunk either way
+        width = self.mesh_devices if (self._mesh_is_real()
+                                      and split.any_kernel) else 1
+        if split.any_kernel and width == 1:
             self._adopt_image()
-        # double buffering applies only where dispatch is actually lazy
-        # (kernel sub-batches): a pure-jnp chunk evaluates eagerly at
-        # dispatch, so holding it back would just delay its partial by a
-        # whole chunk; and lockstep emulation needs isolated walls
-        buffered = (self.double_buffer and split.any_kernel
-                    and not lockstep and not mesh_fast)
 
         results: List[List[merge_lib.QueryResult]] = []
         t_start = clock()
-        t_lockstep = 0.0    # critical-path seconds (emulated mesh clock)
         t_prev = t_start    # previous finalize completion (chunk walls)
-        group_walls: List[float] = []
 
         def stamp() -> float:
-            return t_lockstep if lockstep else clock() - t_start
+            return clock() - t_start
 
         def emit(infl: _Inflight, wall: float) -> None:
             """Record one finalized chunk: telemetry, obs, stats, and the
@@ -1015,8 +832,6 @@ class SpmdBackend:
             stats.packets += 1
             stats.per_node_busy[infl.owner] = \
                 stats.per_node_busy.get(infl.owner, 0.0) + wall
-            if controller is not None:
-                controller.observe(infl.size, wall)
             if on_partial is not None:
                 on_partial(PacketPartial(
                     seq=infl.seq, brick_id=infl.brick_id, start=infl.start,
@@ -1026,92 +841,55 @@ class SpmdBackend:
                 obs.tracer.end(infl.span,
                                t_virtual=obs.tracer.virtual_base + stamp())
 
-        pending: Optional[_Inflight] = None
-
-        def finalize(infl: _Inflight) -> None:
+        def finalize(step: List[_Inflight]) -> None:
+            """Finalize one dispatched step; its chunks share the wall
+            since the previous step finalized."""
             nonlocal t_prev
-            self._finalize_chunk(infl, split)
+            for infl in step:
+                self._finalize_chunk(infl, split)
             now = clock()
-            emit(infl, max(now - t_prev, 1e-9))
+            wall = max(now - t_prev, 1e-9) / len(step)
+            for infl in step:
+                emit(infl, wall)
             t_prev = now
 
+        pending: List[_Inflight] = []
         seq = 0
-        for bid in bricks:
+        for bid in sorted(rec.bricks):
             n = self.store.specs[bid].n_events
             owner = self.store.specs[bid].node
             start = 0
             while start < n:
-                if lockstep:
-                    # one lockstep group: up to `mesh` sub-chunks of this
-                    # brick, each measured in isolation; the group costs
-                    # the MAX of its walls on the mesh clock
-                    group: List[_Inflight] = []
-                    group_walls.clear()
-                    while len(group) < mesh and start < n:
-                        size = self._chunk_size(seq, n - start, ramp,
-                                                controller)
-                        t0 = clock()
-                        infl = self._dispatch_chunk(
-                            plan, split, seq, bid, start, size, owner,
-                            rec.calib_iters, block_shapes)
-                        self._finalize_chunk(infl, split)
-                        group_walls.append(max(clock() - t0, 1e-9))
-                        group.append(infl)
-                        seq += 1
-                        start += size
-                    t_lockstep += max(group_walls)
-                    for infl, wall in zip(group, group_walls):
-                        emit(infl, wall)
-                    continue
-                if mesh_fast:
-                    # one shard_map call per group of up to `mesh` slots;
-                    # partials still per slot, in order
-                    slots: List[Tuple[int, int, int]] = []
-                    while len(slots) < mesh and start < n:
-                        size = self._chunk_size(seq, n - start, ramp,
-                                                controller)
-                        slots.append((seq, start, size))
-                        seq += 1
-                        start += size
-                    spans = None
-                    if obs is not None:
-                        spans = [obs.tracer.begin(
-                            "packet",
-                            t_virtual=obs.tracer.virtual_base + stamp(),
-                            seq=q, brick=bid, start=s0, size=sz, node=owner)
-                            for q, s0, sz in slots]
-                    t0 = clock()
-                    infls = self._dispatch_group(plan, split, slots, bid,
-                                                 owner, rec.calib_iters,
-                                                 block_shapes, spans)
-                    for infl in infls:
-                        self._finalize_chunk(infl, split)
-                    per = max(clock() - t0, 1e-9) / len(slots)
-                    for infl in infls:
-                        emit(infl, per)
-                    continue
-                size = self._chunk_size(seq, n - start, ramp, controller)
-                span = None
+                slots: List[Tuple[int, int, int]] = []
+                while len(slots) < width and start < n:
+                    size = self._chunk_size(seq, n - start, ramp)
+                    slots.append((seq, start, size))
+                    seq += 1
+                    start += size
+                spans = None
                 if obs is not None:
-                    span = obs.tracer.begin(
-                        "packet",
-                        t_virtual=obs.tracer.virtual_base + stamp(),
-                        seq=seq, brick=bid, start=start, size=size,
-                        node=owner)
-                infl = self._dispatch_chunk(plan, split, seq, bid, start,
-                                            size, owner, rec.calib_iters,
-                                            block_shapes, span)
-                if not buffered:
-                    finalize(infl)
+                    spans = [obs.tracer.begin(
+                        "packet", t_virtual=obs.tracer.virtual_base + stamp(),
+                        seq=q, brick=bid, start=s0, size=sz, node=owner)
+                        for q, s0, sz in slots]
+                if width > 1:
+                    step = self._dispatch_group(plan, split, slots, bid,
+                                                owner, rec.calib_iters, spans)
                 else:
-                    if pending is not None:
-                        # chunk i finalizes (host merge + stream emit)
-                        # while chunk i+1's device compute is in flight
-                        finalize(pending)
-                    pending = infl
-                seq += 1
-                start += size
-        if pending is not None:
+                    q, s0, sz = slots[0]
+                    step = [self._dispatch_chunk(
+                        plan, split, q, bid, s0, sz, owner, rec.calib_iters,
+                        None if spans is None else spans[0])]
+                if not split.any_kernel:
+                    # a pure-jnp chunk is already evaluated at dispatch
+                    finalize(step)
+                    continue
+                # step i finalizes (host reads, merge, stream emit) while
+                # step i+1's device work is in flight
+                if pending:
+                    finalize(pending)
+                pending = step
+        if pending:
             finalize(pending)
 
         k = len(job_ids)
@@ -1121,8 +899,7 @@ class SpmdBackend:
         stats.fragment_results = dict(
             zip(plan.materialize_keys(), merged[k:]))
         merged = merged[:k]
-        stats.makespan_s = t_lockstep if lockstep \
-            else clock() - t_start
+        stats.makespan_s = clock() - t_start
 
         end = time.time()
         for jid, m in zip(job_ids, merged):

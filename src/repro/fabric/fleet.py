@@ -97,9 +97,9 @@ class Fleet:
         ``core/backend.py``).
     backend_kwargs:
         Tuning kwargs forwarded to every front-end's backend
-        constructor — the SPMD performance knobs (``use_pallas``,
-        ``interpret``, ``chunk_events``, ``adaptive_chunks``,
-        ``mesh_devices``, ``autotune``, ``double_buffer``; see
+        constructor — the SPMD scan's knobs (``chunk_events``,
+        ``packet_ramp``, ``ramp_factor``, ``use_pallas``,
+        ``interpret``, ``mesh_devices``, ``clock``; see
         ``docs/backends.md``, "Performance tuning").
     gossip_fanout:
         Digest push targets per round; ``None`` (default) adapts to
@@ -228,8 +228,7 @@ class Fleet:
         kwargs = dict(service_kwargs or {})
         kwargs.setdefault("backend", backend)
         if backend_kwargs:
-            # per-frontend backends share the tuning knobs (autotune
-            # winners are cached process-wide, so frontends share sweeps)
+            # per-frontend backends share the scan's knobs
             kwargs.setdefault("backend_kwargs", dict(backend_kwargs))
         for i in range(n_frontends):
             node_id = f"fe{i}"

@@ -139,8 +139,7 @@ def event_filter(scalars, pt, n_tracks, thresholds, *, var_idx: int,
     plane (``tracks[..., 0]``): the Pallas path (``use_pallas=True``) or
     the jnp reference.  ``interpret=None`` auto-detects (compiled on
     TPU/GPU, interpreter on CPU); ``block_e`` / ``block_t`` are the
-    kernel's static block shapes (see :func:`autotune_block_shapes` in
-    ``tune.py`` for the sweep)."""
+    kernel's static block shapes (default ``DEFAULT_BLOCK``)."""
     if use_pallas:
         return event_filter_pallas(
             scalars, pt, n_tracks, thresholds, var_idx=var_idx,

@@ -114,9 +114,7 @@ def _backend_kwargs(args):
     if args.backend != "spmd":
         for flag, name in ((args.use_pallas, "--use-pallas"),
                            (args.chunk_events, "--chunk-events"),
-                           (args.adaptive_chunks, "--adaptive-chunks"),
-                           (args.mesh_devices, "--mesh-devices"),
-                           (args.autotune, "--autotune")):
+                           (args.mesh_devices, "--mesh-devices")):
             if flag:
                 raise SystemExit(
                     f"{name} requires --backend spmd (the simulation "
@@ -129,12 +127,8 @@ def _backend_kwargs(args):
         kw["interpret"] = args.interpret == "interpret"
     if args.chunk_events is not None:
         kw["chunk_events"] = args.chunk_events
-    if args.adaptive_chunks:
-        kw["adaptive_chunks"] = True
     if args.mesh_devices is not None:
         kw["mesh_devices"] = args.mesh_devices
-    if args.autotune:
-        kw["autotune"] = True
     return kw or None
 
 
@@ -422,19 +416,10 @@ def main(argv=None):
     ap.add_argument("--chunk-events", type=int, default=None, metavar="N",
                     help="spmd backend: events per scan chunk "
                          "(= streamed partial granularity)")
-    ap.add_argument("--adaptive-chunks", action="store_true",
-                    help="spmd backend: size chunks from measured scan "
-                         "rate (EWMA ChunkController) instead of a "
-                         "fixed --chunk-events")
     ap.add_argument("--mesh-devices", type=int, default=None, metavar="D",
                     help="spmd backend: shard each brick's chunk groups "
-                         "over a D-device scan mesh (shard_map when D "
-                         "jax devices exist, lockstep emulation "
-                         "otherwise)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="spmd backend: sweep event_filter (block_e, "
-                         "block_t) per chunk shape and use the cached "
-                         "winner")
+                         "over a D-device scan mesh (shard_map; needs D "
+                         "jax devices)")
     ap.add_argument("--fleet", type=int, default=1,
                     help="query mode: number of coherence-fabric "
                          "front-ends (1 = single QueryService)")

@@ -269,9 +269,9 @@ class QueryService:
         and stream fan-out address it by this id).
     backend_kwargs:
         Extra constructor kwargs for a string-selected backend — the
-        SPMD performance knobs (``use_pallas``, ``interpret``,
-        ``chunk_events``, ``adaptive_chunks``, ``mesh_devices``,
-        ``autotune``, ``double_buffer``; see ``docs/backends.md``,
+        SPMD scan's knobs (``chunk_events``, ``packet_ramp``,
+        ``ramp_factor``, ``use_pallas``, ``interpret``,
+        ``mesh_devices``, ``clock``; see ``docs/backends.md``,
         "Performance tuning") or simulation extras.  Rejected alongside
         a pre-built backend instance, same as ``time_model``.
     obs:
@@ -331,9 +331,9 @@ class QueryService:
                     f"{kind!r} backend would silently ignore them")
             kwargs = ({"time_model": time_model, "node_speed": node_speed}
                       if kind == "sim" else {})
-            # performance knobs (use_pallas/interpret/chunk_events/
-            # mesh_devices/autotune/...) pass straight through to the
-            # chosen backend's constructor
+            # scan knobs (use_pallas/interpret/chunk_events/
+            # mesh_devices/...) pass straight through to the chosen
+            # backend's constructor
             kwargs.update(backend_kwargs or {})
             backend = backend_lib.make_backend(kind, self.catalog, store,
                                                **kwargs)

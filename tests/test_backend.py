@@ -10,8 +10,7 @@ from repro.configs.geps_events import reduced
 from repro.core import events as ev
 from repro.core import backend as backend_lib
 from repro.core import merge as merge_lib
-from repro.core.backend import (ChunkController, SimulatedBackend,
-                                SpmdBackend, make_backend)
+from repro.core.backend import SimulatedBackend, SpmdBackend, make_backend
 from repro.core.brick import create_store
 from repro.core.catalog import DONE, MetadataCatalog
 from repro.fabric import SharedCacheTier, adaptive_fanout, rounds_bound
@@ -217,9 +216,9 @@ def test_spmd_pallas_fusion_matches_jnp_plan():
                       chunk_events=32)
     fused = SpmdBackend(MetadataCatalog(store.n_nodes), store,
                         chunk_events=32, use_pallas=True)
-    # the fusion hook actually engages for this window (every target —
-    # roots AND the materialized boolean fragment — is in-family)
-    assert fused._fuse_plan(plan) is not None
+    # the kernel takes the whole window (every target — roots AND the
+    # materialized boolean fragment — is in-family)
+    assert fused._split_plan(plan).full_kernel
     out_ref = run_window(ref, store, exprs, calib=2)
     out_fused = run_window(fused, store, exprs, calib=2)
     assert_window_equivalent(out_ref, out_fused)
@@ -230,7 +229,7 @@ def test_spmd_pallas_falls_back_on_out_of_family_target():
     fused = SpmdBackend(MetadataCatalog(store.n_nodes), store,
                         use_pallas=True)
     plan = plan_window(["pt_lead > 60 || n_tracks >= 8"])
-    assert fused._fuse_plan(plan) is None
+    assert not fused._split_plan(plan).full_kernel
     merged, _, _ = run_window(fused, store,
                               ["pt_lead > 60 || n_tracks >= 8"])
     sim = SimulatedBackend(MetadataCatalog(store.n_nodes), store,
@@ -255,8 +254,7 @@ def test_spmd_mixed_window_splits_kernel_and_jnp():
                         chunk_events=32, use_pallas=True)
     plan = plan_window(MIXED)
     split = fused._split_plan(plan)
-    assert split.any_kernel and not split.full_kernel
-    assert fused._fuse_plan(plan) is None  # not FULLY fused...
+    assert split.any_kernel and not split.full_kernel  # not FULLY fused...
     out_plain = run_window(plain, store, MIXED, calib=2)
     out_fused = run_window(fused, store, MIXED, calib=2)
     assert_window_equivalent(out_plain, out_fused)
@@ -304,54 +302,50 @@ def test_mixed_split_bit_identity_property():
     check()
 
 
-# ----------------------- adaptive chunk sizing -------------------------- #
-def test_chunk_controller_converges_to_target():
-    ctl = ChunkController(initial=64, min_chunk=8, max_chunk=4096,
-                          target_s=0.01, alpha=0.5, hysteresis=0.0)
-    assert ctl.chunk() == 64
-    for _ in range(32):
-        ctl.observe(events=64, wall_s=0.001)  # steady 64k events/s
-    # rate EWMA converged; proposal = rate * target_s = 640
-    assert abs(ctl.scan_rate - 64_000) / 64_000 < 1e-6
-    assert ctl.chunk() == 640
-    # clamping: a crawling scan floors at min_chunk
-    for _ in range(64):
-        ctl.observe(events=8, wall_s=10.0)
-    assert ctl.chunk() == 8
-
-
-def test_chunk_controller_hysteresis_dead_band():
-    ctl = ChunkController(initial=100, target_s=1.0, alpha=1.0,
-                          hysteresis=0.25)
-    ctl.observe(events=100, wall_s=1.0)   # rate 100 -> proposal 100
-    assert ctl.chunk() == 100
-    ctl.observe(events=110, wall_s=1.0)   # +10% < 25% dead-band: held
-    assert ctl.chunk() == 100
-    ctl.observe(events=200, wall_s=1.0)   # +100%: moves
-    assert ctl.chunk() == 200
-    # ignores degenerate observations
-    ctl.observe(events=0, wall_s=1.0)
-    ctl.observe(events=10, wall_s=0.0)
-    assert ctl.chunk() == 200
-
-
-def test_chunk_controller_validation():
-    with pytest.raises(ValueError):
-        ChunkController(alpha=0.0)
-    with pytest.raises(ValueError):
-        ChunkController(min_chunk=0)
-    with pytest.raises(ValueError):
-        ChunkController(min_chunk=64, max_chunk=8)
-    with pytest.raises(ValueError):
-        ChunkController(target_s=0.0)
-    with pytest.raises(ValueError):
-        ChunkController(hysteresis=-0.1)
+# ----------------------- chunk tiling ----------------------------------- #
+@pytest.mark.parametrize("exprs", [POOL[:3], MIXED], ids=["kernel", "mixed"])
+@pytest.mark.parametrize("chunk, ramp", [(6, None), (16, None), (96, None),
+                                         (16, 2), (6, 1), (8, 3)])
+def test_spmd_partials_tile_every_brick_in_order(exprs, chunk, ramp):
+    """The partials tile every brick in merge order: ``seq`` counts up
+    from 0, ``(brick_id, start)`` ascend and cover each brick with no gap
+    or overlap, each size is the stream ramp's cap and then
+    ``chunk_events`` (clipped to the brick's remainder), availability
+    stamps never decrease, and the finals count what the simulated grid
+    counts."""
+    from repro.core.packets import ramp_cap
+    store = make_store(n_events=96)
+    spmd = SpmdBackend(MetadataCatalog(store.n_nodes), store,
+                       chunk_events=chunk, packet_ramp=ramp, use_pallas=True)
+    merged, stats, partials = run_window(spmd, store, exprs, calib=2)
+    want = []
+    for bid in sorted(store.bricks):
+        n, start = store.specs[bid].n_events, 0
+        while start < n:
+            size = chunk
+            if ramp is not None:
+                size = min(size, max(1, int(ramp_cap(len(want), ramp, 2.0))))
+            size = min(size, n - start)
+            want.append((len(want), bid, start, size))
+            start += size
+    assert [(p.seq, p.brick_id, p.start, p.size) for p in partials] == want
+    assert stats.packets == len(want)
+    assert stats.events_scanned == stats.kernel_events == store.n_events
+    times = [p.t_virtual for p in partials]
+    assert times == sorted(times)
+    sim = SimulatedBackend(MetadataCatalog(store.n_nodes), store,
+                           adaptive_packets=False)
+    ref, _, _ = run_window(sim, store, exprs, calib=2)
+    for a, b in zip(ref, merged):
+        assert (a.n_selected, a.n_processed) == (b.n_selected, b.n_processed)
+        assert np.array_equal(a.hist, b.hist)
+        assert np.array_equal(a.selected_ids, b.selected_ids)
+        assert np.isclose(a.sum_var, b.sum_var, rtol=1e-6)
 
 
 class TickClock:
     """Deterministic injectable clock: advances a fixed dt per call, so
-    measured 'walls' — and everything derived from them, like adaptive
-    chunk boundaries — replay identically run over run."""
+    measured walls and stamps replay identically run over run."""
 
     def __init__(self, dt=0.003):
         self.t, self.dt = 0.0, dt
@@ -361,50 +355,18 @@ class TickClock:
         return self.t
 
 
-def test_spmd_adaptive_chunks_resize_and_stay_correct():
-    """Adaptive chunks change packetization, not answers: with the same
-    injected clock, the jnp and kernel-split scans pick the SAME chunk
-    boundaries and stay bit-identical; against a sim reference the exact
-    counts match and sum_var agrees to float regrouping."""
-    store = make_store()
-
-    def adaptive(**kw):
-        return SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                           chunk_events=8, adaptive_chunks=True,
-                           chunk_target_s=0.05, clock=TickClock(), **kw)
-
-    out_jnp = run_window(adaptive(), store, POOL[:3], calib=2)
-    out_ker = run_window(adaptive(use_pallas=True), store, POOL[:3],
-                         calib=2)
-    assert_window_equivalent(out_jnp, out_ker)
-    partials = out_jnp[2]
-    # the controller actually moved chunk sizes off the initial value
-    assert len({p.size for p in partials}) > 1
-    # exact-count agreement with the simulated reference (sum_var may
-    # regroup: adaptive chunk boundaries differ from sim packets)
-    sim = SimulatedBackend(MetadataCatalog(store.n_nodes), store,
-                           adaptive_packets=False)
-    want, _, _ = run_window(sim, store, POOL[:3], calib=2)
-    for a, b in zip(want, out_jnp[0]):
-        assert a.n_selected == b.n_selected
-        assert a.n_processed == b.n_processed
-        assert np.allclose(a.sum_var, b.sum_var)
-        assert np.array_equal(a.hist, b.hist)
-
-
 def test_spmd_adaptive_chunks_deterministic_flight_log(tmp_path):
-    """Adaptive chunk sizing must not break the flight recorder's
-    byte-identical replayability: with the backend clock injected, two
-    identical runs produce identical chunk boundaries, identical stream
-    snapshots, and byte-identical flight logs."""
+    """The SPMD scan keeps the flight recorder byte-identical: with the
+    backend clock injected, two identical runs of fixed chunks (ramped
+    at the start) produce identical stream snapshots and byte-identical
+    flight logs."""
     from repro.fabric.fleet import Fleet
 
     def one_run(path):
         store = make_store()
         fleet = Fleet(store, 2, backend="spmd", obs=True, flight=True,
-                      backend_kwargs=dict(chunk_events=8,
-                                          adaptive_chunks=True,
-                                          chunk_target_s=0.05,
+                      backend_kwargs=dict(chunk_events=8, packet_ramp=2,
+                                          use_pallas=True,
                                           clock=TickClock()))
         for i, e in enumerate(POOL[:3]):
             fleet.submit(e, tenant=f"t{i}", stream=True)
@@ -419,36 +381,10 @@ def test_spmd_adaptive_chunks_deterministic_flight_log(tmp_path):
 
 
 # ----------------------- mesh-sharded chunks ---------------------------- #
-def test_spmd_mesh_lockstep_emulation_bit_identical():
-    """mesh_devices > jax devices: the mesh is emulated with lockstep
-    critical-path accounting — results and partials stay bit-identical
-    to the single-device scan, stamps ride the lockstep clock."""
-    store = make_store(n_events=96)
-    base = SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                       chunk_events=16, use_pallas=True)
-    mesh = SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                       chunk_events=16, use_pallas=True, mesh_devices=4)
-    assert not mesh._mesh_is_real()
-    out_base = run_window(base, store, POOL, calib=2)
-    out_mesh = run_window(mesh, store, POOL, calib=2)
-    assert_window_equivalent(out_base, out_mesh)
-    stats, partials = out_mesh[1], out_mesh[2]
-    # lockstep makespan is the sum of per-group maxima: no larger than
-    # the serial sum of walls, no smaller than the largest single wall
-    serial = sum(t.wall_s for t in stats.packet_telemetry)
-    assert max(t.wall_s for t in stats.packet_telemetry) \
-        <= stats.makespan_s <= serial + 1e-9
-    times = [p.t_virtual for p in partials]
-    assert times == sorted(times)
-
-
-def test_spmd_mesh_wider_than_accelerator_devices_raises(monkeypatch):
-    """Lockstep emulation is a CPU-only stand-in: on an accelerator a
-    mesh wider than the devices fails instead of reporting made-up
-    times."""
+def test_spmd_mesh_wider_than_accelerator_devices_raises():
+    """A mesh wider than jax's devices fails, on every platform."""
     import jax
     store = make_store(n_events=32)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = SpmdBackend(MetadataCatalog(store.n_nodes), store,
                        chunk_events=16, use_pallas=True,
                        mesh_devices=len(jax.devices()) + 1)
@@ -456,61 +392,34 @@ def test_spmd_mesh_wider_than_accelerator_devices_raises(monkeypatch):
         mesh._mesh_is_real()
 
 
-def test_spmd_real_mesh_shard_map_bit_identical():
+@pytest.mark.parametrize("devices, chunk, window",
+                         [(2, 16, "POOL"), (2, 6, "MIXED"), (4, 6, "POOL")])
+def test_spmd_real_mesh_shard_map_bit_identical(devices, chunk, window):
     """With enough physical devices, mesh groups execute as ONE
     shard_map call over stacked padded sub-chunks — and partials stay
-    bit-identical to the sequential scan (subprocess: jax pins its
-    device count at first init)."""
+    bit-identical to the sequential scan, also for a tail group with a
+    dummy slab (chunks of 6 in bricks of 16) and a mixed window
+    (subprocess: jax pins its device count at first init)."""
     from tests.test_multidevice import run_with_devices
-    run_with_devices("""
-        from tests.test_backend import (make_store, run_window, POOL,
+    run_with_devices(f"""
+        from tests.test_backend import (make_store, run_window, POOL, MIXED,
                                         assert_window_equivalent)
         from repro.core.backend import SpmdBackend
         from repro.core.catalog import MetadataCatalog
-        assert len(jax.devices()) == 2
+        assert len(jax.devices()) == {devices}
         store = make_store(n_events=96)
         base = SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                           chunk_events=16, use_pallas=True)
+                           chunk_events={chunk}, use_pallas=True)
         mesh = SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                           chunk_events=16, use_pallas=True,
-                           mesh_devices=2)
-        out_base = run_window(base, store, POOL, calib=2)
-        out_mesh = run_window(mesh, store, POOL, calib=2)
+                           chunk_events={chunk}, use_pallas=True,
+                           mesh_devices={devices})
+        out_base = run_window(base, store, {window}, calib=2)
+        out_mesh = run_window(mesh, store, {window}, calib=2)
         assert mesh._mesh_is_real()
         assert_window_equivalent(out_base, out_mesh)
         assert out_mesh[1].kernel_events == store.n_events
         print("OK")
-    """, n=2)
-
-
-def test_spmd_double_buffer_preserves_order_and_results():
-    store = make_store(n_events=96)
-    on = SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                     chunk_events=16, use_pallas=True, double_buffer=True)
-    off = SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                      chunk_events=16, use_pallas=True,
-                      double_buffer=False)
-    assert_window_equivalent(run_window(on, store, MIXED, calib=2),
-                             run_window(off, store, MIXED, calib=2))
-
-
-def test_spmd_autotune_uses_cached_winner():
-    from repro.kernels.event_filter import tune as ef_tune
-    ef_tune.clear_cache()
-    store = make_store(n_events=96)
-    tuned = SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                        chunk_events=32, use_pallas=True, autotune=True)
-    plain = SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                        chunk_events=32, use_pallas=True)
-    out_tuned = run_window(tuned, store, POOL[:3], calib=2)
-    out_plain = run_window(plain, store, POOL[:3], calib=2)
-    assert_window_equivalent(out_plain, out_tuned)
-    assert tuned.last_autotune is not None
-    assert tuned.last_autotune.speedup_vs_default >= 1.0
-    assert len(ef_tune.cached_shapes()) == 1
-    # a second window of the same shape class pays no new sweep
-    run_window(tuned, store, POOL[:3], calib=2)
-    assert len(ef_tune.cached_shapes()) == 1
+    """, n=devices)
 
 
 def test_service_backend_kwargs_thread_through():
@@ -537,8 +446,8 @@ def force_streaming(monkeypatch):
 
 @pytest.mark.parametrize("exprs", [POOL[:3], MIXED],
                          ids=["kernel", "mixed"])
-@pytest.mark.parametrize("double_buffer", [True, False])
-def test_spmd_resident_matches_streamed(monkeypatch, exprs, double_buffer):
+@pytest.mark.parametrize("ramp", [None, 2])
+def test_spmd_resident_matches_streamed(monkeypatch, exprs, ramp):
     """Chunks sliced from the resident image give the per-chunk partials,
     prefix snapshots, fragment results and finals of chunks streamed
     from the host, bit for bit, in kernel and mixed windows."""
@@ -552,8 +461,8 @@ def test_spmd_resident_matches_streamed(monkeypatch, exprs, double_buffer):
         # chunks of 6 over bricks of 16: starts inside a brick and a
         # shorter tail chunk
         return SpmdBackend(MetadataCatalog(store.n_nodes), store,
-                           chunk_events=6, use_pallas=True,
-                           double_buffer=double_buffer)
+                           chunk_events=6, packet_ramp=ramp,
+                           use_pallas=True)
 
     # streamed first: a backend built later over the same store would
     # share the image the resident one holds

@@ -218,28 +218,6 @@ def test_default_interpret_env_override(monkeypatch):
         K.default_interpret()
 
 
-def test_autotune_block_shapes_caches_and_beats_default():
-    from repro.kernels.event_filter import tune as ef_tune
-    scalars, tracks, n_tracks = _ef_operands(n=96, t=48)
-    th = jnp.array([[40.0, -jnp.inf], [15.0, 15.0], [2.0, 2.0],
-                    [-1.0, -1.0]], jnp.float32)
-    cache = {}
-    tuned = ef_tune.autotune_block_shapes(
-        scalars, tracks[..., 0], n_tracks, th, var_idx=(0, 0),
-        calib_iters=2, repeats=2, cache=cache)
-    assert tuned.speedup_vs_default >= 1.0
-    assert tuned.roofline["gbytes_per_s"] > 0
-    # candidates that clamp to the same effective shape timed only once
-    effective = {(min(be, 96), min(bt, 48))
-                 for be, bt in ef_tune.CANDIDATES}
-    assert len(tuned.measurements) == len(effective)
-    # second call with the same shape class is a pure cache hit
-    again = ef_tune.autotune_block_shapes(
-        scalars, tracks[..., 0], n_tracks, th, var_idx=(0, 0),
-        calib_iters=2, repeats=2, cache=cache)
-    assert again is tuned and len(cache) == 1
-
-
 # ------------------------------ rglru scan ------------------------------- #
 @pytest.mark.parametrize("b,s,w,bb,bs,bw", [
     (2, 64, 32, 2, 16, 32),

@@ -430,38 +430,39 @@ def test_sim_and_spmd_ticket_spans_identical():
 SPMD_KW = {"use_pallas": True, "chunk_events": 16}
 
 
-def _spmd_run(obs, double_buffer):
+def _spmd_run(obs, exprs):
     store = make_store(seed=19)
     svc = QueryService(store, backend="spmd", obs=obs,
-                       backend_kwargs=dict(SPMD_KW,
-                                           double_buffer=double_buffer))
+                       backend_kwargs=SPMD_KW)
     tids = [svc.submit(e, tenant=f"t{i % 2}", stream=True)
-            for i, e in enumerate(EXPRS)]
+            for i, e in enumerate(exprs)]
     svc.drain()
     svc.close()
     return store, svc, tids
 
 
-@pytest.mark.parametrize("double_buffer, resident",
-                         [(True, True), (False, True), (True, False),
-                          (False, False)],
-                         ids=["True", "False", "True-streamed",
-                              "False-streamed"])
-def test_spmd_scan_phase_spans(monkeypatch, double_buffer, resident):
+@pytest.mark.parametrize("mixed, resident",
+                         [(False, True), (True, True), (False, False),
+                          (True, False)],
+                         ids=["kernel", "mixed", "kernel-streamed",
+                              "mixed-streamed"])
+def test_spmd_scan_phase_spans(monkeypatch, mixed, resident):
     """Each kernel chunk's host work lies in exactly one ``stage``,
     ``launch``, ``wait`` and ``merge`` span under its ``packet`` span
-    (double buffering interleaves chunks, so the parent is explicit);
+    (the pipeline interleaves chunks, so the parent is explicit);
     ``spmd.h2d_bytes`` counts the inputs sent to the device: a resident
     store's once, in the ``upload`` span, a streamed store's chunk by
-    chunk in every window; the finals are those of the untraced
-    (resident) run."""
+    chunk in every window (the full tracks for a mixed window, the
+    ``pt`` plane for a kernel-only one); the finals are those of the
+    untraced (resident) run."""
     from repro.core import backend as backend_lib
     from repro.obs import SPAN_NAMES
+    exprs = EXPRS if mixed else EXPRS[:2]
     obs = Observability(origin="fe0")
     with monkeypatch.context() as m:
         if not resident:
             m.setattr(backend_lib, "_device_room", lambda device: 0)
-        store, svc, tids = _spmd_run(obs, double_buffer)
+        store, svc, tids = _spmd_run(obs, exprs)
     recs = obs.tracer.records()
     assert validate_records(recs) == []
     assert {r["name"] for r in recs} <= set(SPAN_NAMES)
@@ -477,16 +478,20 @@ def test_spmd_scan_phase_spans(monkeypatch, double_buffer, resident):
             assert p["t0_wall"] <= r["t0_wall"] <= r["t1_wall"] <= p["t1_wall"]
     assert all(k == {"stage": 1, "launch": 1, "wait": 1, "merge": 1}
                for k in phases.values())
-    per_window = sum(int(b[k].nbytes) for b in store.bricks.values()
-                     for k in ("scalars", "tracks", "n_tracks"))
+    inputs = sum(int(b[k].nbytes) for b in store.bricks.values()
+                 for k in ("scalars", "tracks", "n_tracks"))
+    per_window = inputs if mixed else sum(
+        int(b["scalars"].nbytes + b["n_tracks"].nbytes
+            + b["tracks"][..., 0].nbytes) for b in store.bricks.values())
     windows = obs.metrics.value("window.dispatched")
     staged = sum(r["attrs"]["bytes"] for r in recs if r["name"] == "stage")
     uploads = [r for r in recs if r["name"] == "upload"]
     if resident:
         assert staged == 0
-        assert [u["attrs"]["bytes"] for u in uploads] == [per_window]
-        assert obs.metrics.value("spmd.h2d_bytes") == per_window > 0
-        assert obs.metrics.value("spmd.resident_chunks") == len(packets)
+        assert [u["attrs"]["bytes"] for u in uploads] == [inputs]
+        assert obs.metrics.value("spmd.h2d_bytes") == inputs > 0
+        assert obs.metrics.value("spmd.resident_chunks") == (
+            len(packets) if mixed else 0)
     else:
         assert not uploads
         assert obs.metrics.value("spmd.h2d_bytes") == staged \
@@ -495,7 +500,7 @@ def test_spmd_scan_phase_spans(monkeypatch, double_buffer, resident):
     assert obs.metrics.value("stream.published") == sum(
         r["attrs"]["published"] for r in merges) > 0
 
-    _, base, base_tids = _spmd_run(None, double_buffer)
+    _, base, base_tids = _spmd_run(None, exprs)
     for a, b in zip(tids, base_tids):
         ta, tb = svc.result(a), base.result(b)
         assert ta.status == tb.status == SERVED

@@ -19,8 +19,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.geps_events import CONFIG, reduced
 from repro.kernels.event_filter import ops as ef_ops
-from repro.kernels.event_filter import tune as ef_tune
-from repro.kernels.event_filter.kernel import (event_filter_batch_pallas,
+from repro.kernels.event_filter.kernel import (DEFAULT_BLOCK,
+                                               event_filter_batch_pallas,
                                                event_filter_pallas)
 
 K = 3
@@ -116,19 +116,19 @@ def test_sharded_kernel_call_compiles_on_4_devices(scan_mesh):
     _assert_kernel(compiled)
 
 
-@pytest.mark.parametrize("block", ef_tune.candidates_for(
-    WIDTHS["paper"][0], WIDTHS["paper"][2], K),
-    ids=lambda block: f"{block[0]}x{block[1]}")
-def test_autotune_candidates_compile_at_paper_width(one_chip, block):
-    """Every block shape autotune may sweep at the paper's width
-    compiles; the over-budget ones were dropped before the sweep."""
-    thr = jax.ShapeDtypeStruct((4, K), jnp.float32, sharding=one_chip)
+@pytest.mark.parametrize("k", [1, 2, 3, 5, 8, 13, 21, 34, 55, 64],
+                         ids=lambda k: f"K={k}")
+def test_autotune_candidates_compile_at_paper_width(one_chip, k):
+    """The kernel compiles at the paper's width at its default block
+    shape for each query width K a window may bring, up to the
+    service's ``max_batch`` of 64."""
+    thr = jax.ShapeDtypeStruct((4, k), jnp.float32, sharding=one_chip)
     fn = lambda sc, pt, ntr, th: event_filter_batch_pallas(
-        sc, pt, ntr, th, var_idx=tuple(range(K)), calib_iters=CALIB,
-        block_e=block[0], block_t=block[1], interpret=False)
+        sc, pt, ntr, th, var_idx=tuple(range(k)), calib_iters=CALIB,
+        block_e=DEFAULT_BLOCK[0], block_t=DEFAULT_BLOCK[1],
+        interpret=False)
     _assert_kernel(jax.jit(fn).lower(*_operands("paper", one_chip),
                                      thr).compile())
-
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
